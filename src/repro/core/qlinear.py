@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Optional, Tuple
 
 import jax
@@ -165,54 +164,60 @@ def quantize_linear(w: jax.Array, act_stat: Optional[jax.Array],
     """PTQ1.61 initial quantization of one (…, K, N) weight (no learning).
 
     act_stat: per-input-channel saliency statistic E[|x|] (K,) (or stacked).
+    Without one, each (K, N) slice ranks its channels by its own mean |w|.
     Without a mask (ablation), every channel binarizes (k_s=multiple is the
     floor, so we use k_s=0 semantics via an empty salient slice).
+
+    Stacked weights (layers and/or experts) are quantized one (K, N) slice
+    at a time, statistic included, so the f32 working set is one slice's
+    and never the whole stack's.
     """
     k, n = w.shape[-2], w.shape[-1]
-    if act_stat is None:
-        act_stat = jnp.mean(jnp.abs(w.astype(jnp.float32)), axis=-1)
-    if qcfg.hessian_mask:
-        stat = sal.hessian_saliency(jnp.square(act_stat), w)
-    else:
-        stat = act_stat
-
-    def one(wm, sv):
-        if qcfg.use_mask:
-            _, perm, k_s = sal.structured_mask(sv, qcfg.ratio, qcfg.multiple)
-        else:
-            perm = jnp.arange(k, dtype=jnp.int32)
-            k_s = 0
-        wp = wm[perm]
-        ws, wb = wp[:k_s], wp[k_s:]
-        if k_s:
-            q4 = int4.quantize_int4(ws)
-            w4 = pack.pack_nibbles(q4["q"], axis=-2)
-            s4, z4 = q4["s"], q4["z"]
-        else:
-            w4 = jnp.zeros((0, n), jnp.uint8)
-            s4 = z4 = jnp.zeros((0,), jnp.float32)
-        b = binarize.binarize_init(wb)
-        bits = pack.pack_bits(b["sign"], axis=-2)
-        return (perm, w4, s4, z4, bits, b["alpha_s"], b["alpha_r1"],
-                b["alpha_r2"]), k_s
-
     if w.ndim == 2:
-        (fields), k_s = one(w, stat)
+        fields = _quantize_slice(w, act_stat, qcfg)
     else:
-        # stacked (layers and/or experts): flatten ALL leading dims, apply
-        # per (K, N) slice, restore the leading shape on every field
+        # flatten ALL leading dims, apply per (K, N) slice, restore the
+        # leading shape on every field
         lead = w.shape[:-2]
         wf = w.reshape((-1,) + w.shape[-2:])
-        sf = (stat.reshape((-1, stat.shape[-1]))
-              if stat.ndim > 1 else None)
-        outs = [one(wf[i], stat if sf is None else sf[i])
+        sf = (act_stat.reshape((-1, k))
+              if act_stat is not None and act_stat.ndim > 1 else None)
+        outs = [_quantize_slice(wf[i], act_stat if sf is None else sf[i],
+                                qcfg)
                 for i in range(wf.shape[0])]
-        k_s = outs[0][1]
         fields = tuple(
-            jnp.stack([o[0][j] for o in outs]).reshape(
-                lead + outs[0][0][j].shape)
+            jnp.stack([o[j] for o in outs]).reshape(lead + outs[0][j].shape)
             for j in range(8))
+    k_s = 2 * fields[1].shape[-2]           # w4 packs two codes per byte
     return QLinear(*fields, k_s=k_s, k=k, n=n, use_kernel=qcfg.use_kernel)
+
+
+def _quantize_slice(wm: jax.Array, stat: Optional[jax.Array],
+                    qcfg: QuantConfig) -> Tuple[jax.Array, ...]:
+    """Quantize one (K, N) slice; returns the eight QLinear array fields."""
+    k, n = wm.shape
+    if stat is None:
+        stat = jnp.mean(jnp.abs(wm.astype(jnp.float32)), axis=-1)
+    if qcfg.hessian_mask:
+        stat = sal.hessian_saliency(jnp.square(stat), wm)
+    if qcfg.use_mask:
+        _, perm, k_s = sal.structured_mask(stat, qcfg.ratio, qcfg.multiple)
+    else:
+        perm = jnp.arange(k, dtype=jnp.int32)
+        k_s = 0
+    wp = wm[perm]
+    ws, wb = wp[:k_s], wp[k_s:]
+    if k_s:
+        q4 = int4.quantize_int4(ws)
+        w4 = pack.pack_nibbles(q4["q"], axis=-2)
+        s4, z4 = q4["s"], q4["z"]
+    else:
+        w4 = jnp.zeros((0, n), jnp.uint8)
+        s4 = z4 = jnp.zeros((0,), jnp.float32)
+    b = binarize.binarize_init(wb)
+    bits = pack.pack_bits(b["sign"], axis=-2)
+    return (perm, w4, s4, z4, bits, b["alpha_s"], b["alpha_r1"],
+            b["alpha_r2"])
 
 
 # ---------------------------------------------------------------------------

@@ -69,7 +69,7 @@ def pipeline_apply(block_fn: Callable[[Tree, jax.Array], jax.Array],
         return outs
 
     pspec = jax.tree.map(lambda _: PS(axis), stage_params)
-    from repro.models.common import shard_map_compat
-    fn = shard_map_compat(local, mesh=mesh,
-                          in_specs=(pspec, PS()), out_specs=PS())
+    # replication checking off: the ring body's collectives are untyped
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(pspec, PS()),
+                       out_specs=PS(), check_vma=False)
     return fn(stage_params, x_micro)

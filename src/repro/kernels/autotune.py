@@ -10,10 +10,14 @@ module replaces the constants with a small static cost model:
 * **feasibility** — every block dim must divide its array dim (the
   kernels have no remainder handling), ``bn`` must keep the 128-lane
   alignment, and ``bk`` must be a *common* divisor of the int4 and
-  binary K spans (a multiple of 8 so packed bytes split evenly);
-* **VMEM budget** — double-buffered input tiles plus the f32
-  accumulator must fit ``vmem_budget`` (default 8 MiB of the ~16 MiB
-  v5e VMEM, leaving room for Pallas' own pipelining);
+  binary K spans (a multiple of 8 so packed bytes split evenly).  With
+  ``tpu_tiling=True`` the Mosaic block floors apply on top: ``bk`` a
+  multiple of 128 (it is the lane dim of the activation and α_in
+  blocks) and ``bm`` either all of M or a multiple of 8;
+* **VMEM budget** — double-buffered input tiles, the f32 accumulator
+  AND the in-kernel unpack/dot temporaries must fit ``vmem_budget``
+  (default 12 MiB of the 16 MiB scoped VMEM Mosaic grants a v5e
+  kernel, the rest a margin for the model's error);
 * **HBM bytes per call** — weight bytes stream once per M tile,
   activation bytes once per N tile, so the model prefers the largest
   feasible ``bm``/``bn`` (for decode M this collapses to ``bm=M`` and,
@@ -35,9 +39,12 @@ from typing import Optional, Tuple
 
 from repro.launch.hlo_analysis import HBM_BW, PEAK_FLOPS
 
-# Input tiles are double-buffered by the Pallas pipeline; keep their two
-# copies plus the resident f32 accumulator inside half of VMEM.
-VMEM_BUDGET = 8 * 1024 * 1024
+# Mosaic's default scoped-VMEM limit on v5e is 16 MiB; the footprint
+# models below count pipeline buffers and kernel temporaries, and keep a
+# quarter of the limit as margin for what they miss.
+VMEM_BUDGET = 12 * 1024 * 1024
+LANE = 128            # TPU lane width: last-dim floor of a Mosaic block
+SUBLANE = 8           # second-to-last-dim floor of a Mosaic block
 BM_CAP = 256          # MXU saturates at 128 rows; 256 amortizes setup
 BK_CAP = 512
 BN_CAP = 32768
@@ -82,18 +89,21 @@ def common_bk(k_s: int, k_b: int, cap: Optional[int] = None,
 
 def resolve_blocks(m: int, k_s: int, k_b: int, n: int,
                    bm: Optional[int], bn: Optional[int], bk: Optional[int],
-                   *, align: int = 8,
-                   bk_default: int = 256) -> Tuple[int, int, Optional[int]]:
+                   *, align: int = 8, bk_default: int = 256,
+                   tpu_tiling: bool = False,
+                   ) -> Tuple[int, int, Optional[int]]:
     """Shared block-dim resolution for all three packed kernels.
 
     Missing dims come from the autotuner (legacy MXU constants when no
     feasible choice exists); explicit dims are clamped to the array and
     a ``bk`` that fails to divide a K span is repaired to the largest
-    common divisor at or below it (multiple of ``align``).  Returns
-    ``bk=None`` when no feasible K block exists — callers raise their
-    kernel-specific error.
+    common divisor at or below it (multiple of ``align``, or of
+    :data:`LANE` under ``tpu_tiling``).  Returns ``bk=None`` when no
+    feasible K block exists — callers raise their kernel-specific error.
     """
-    choice = choose_blocks(m, k_s, k_b, n)
+    choice = choose_blocks(m, k_s, k_b, n, tpu_tiling=tpu_tiling)
+    if tpu_tiling:
+        align = LANE
     if bm is None:
         bm = choice.bm if choice else min(BM_CAP, m)
     if bn is None:
@@ -109,14 +119,20 @@ def resolve_blocks(m: int, k_s: int, k_b: int, n: int,
 
 def kernel_vmem_bytes(bm: int, bn: int, bk: int) -> int:
     """Per-step VMEM footprint of the mixed kernel: double-buffered
-    input tiles (x bf16, packed nibbles + bits, f32 scale vectors) plus
-    the revisited f32 accumulator tile."""
+    input tiles (x bf16, packed nibbles + bits, f32 scale vectors), the
+    revisited f32 accumulator tile, and the temporaries the kernel body
+    materializes — the unpacked (bk, bn) weight tile (int32/f32 codes
+    plus their bf16 MXU copy), the (bm, bn) f32 dot result and its
+    scaled copy, and the (bm, bk) f32/bf16 activation casts.  Fitted
+    from above to Mosaic's v5e scoped-VMEM accounting: at bk=128 the
+    unpack temporaries dominate once bn reaches a few thousand."""
     inputs = (bm * bk * 2            # x tile, bf16
               + (bk // 2) * bn       # w4 tile, u8
               + (bk // 8) * bn       # bits tile, u8
               + 3 * bk * 4           # s4 / z4 / alpha_in slices
               + bn * 4)              # alpha_out slice
-    return 2 * inputs + bm * bn * 4
+    temps = bk * bn * (4 + 2) + bm * bn * (4 + 4) + bm * bk * (4 + 2)
+    return 2 * inputs + bm * bn * 4 + temps
 
 
 def gather_in_kernel_ok(choice: BlockChoice, m: int, k: int,
@@ -167,12 +183,15 @@ def modeled_time_s(m: int, k: int, n: int, hbm_bytes: int) -> float:
 
 
 def choose_blocks(m: int, k_s: int, k_b: int, n: int,
-                  vmem_budget: Optional[int] = None) -> Optional[BlockChoice]:
+                  vmem_budget: Optional[int] = None, *,
+                  tpu_tiling: bool = False) -> Optional[BlockChoice]:
     """Pick (bm, bn, bk) for one mixed/int4/binary matmul call.
 
     Pass ``k_s=0`` for a pure-binary layout or ``k_b=0`` for pure int4.
     Returns None when no feasible tiling exists (misaligned N, no common
     K block, or a degenerate shape) — callers fall back to XLA.
+    ``tpu_tiling=True`` restricts the search to blocks Mosaic accepts
+    (see the module docstring); interpret mode has no such floors.
 
     The memoization IS the dispatch cache: serving decodes hit the same
     few (M, k_s, k_b, N) keys every step.  The module-level knobs
@@ -183,24 +202,28 @@ def choose_blocks(m: int, k_s: int, k_b: int, n: int,
     return _choose_blocks_cached(
         m, k_s, k_b, n,
         VMEM_BUDGET if vmem_budget is None else vmem_budget,
-        BM_CAP, BK_CAP, BN_CAP)
+        BM_CAP, BK_CAP, BN_CAP, tpu_tiling)
 
 
 @functools.lru_cache(maxsize=4096)
 def _choose_blocks_cached(m: int, k_s: int, k_b: int, n: int,
                           vmem_budget: int, bm_cap: int, bk_cap: int,
-                          bn_cap: int) -> Optional[BlockChoice]:
+                          bn_cap: int, tpu_tiling: bool,
+                          ) -> Optional[BlockChoice]:
     if m <= 0 or n <= 0 or k_s + k_b <= 0:
         return None
-    if n % 128 != 0:
+    if n % LANE != 0:
         return None
-    bk0 = common_bk(k_s, k_b, cap=bk_cap)
+    bk_align = LANE if tpu_tiling else 8
+    bk0 = common_bk(k_s, k_b, cap=bk_cap, align=bk_align)
     if bk0 is None:
         return None
     k = k_s + k_b
-    bks = tuple(d for d in _divisors(bk0, bk_cap) if d % 8 == 0)
-    bns = tuple(d for d in _divisors(n, bn_cap) if d % 128 == 0)
+    bks = tuple(d for d in _divisors(bk0, bk_cap) if d % bk_align == 0)
+    bns = tuple(d for d in _divisors(n, bn_cap) if d % LANE == 0)
     bms = _divisors(m, bm_cap) or (m,)
+    if tpu_tiling:
+        bms = tuple(d for d in bms if d == m or d % SUBLANE == 0)
     best: Optional[BlockChoice] = None
     for bm in bms:
         for bn in bns:

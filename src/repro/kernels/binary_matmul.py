@@ -40,7 +40,7 @@ def _kernel(x_ref, bits_ref, a_in_ref, a_out_ref, o_ref, *, bk, bn):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32) * a_in_ref[...][None, :]
+    x = x_ref[...].astype(jnp.float32) * a_in_ref[...]
     sign = _unpack_bits_block(bits_ref[...], bk, bn)
     acc = jax.lax.dot(x.astype(jnp.bfloat16), sign,
                       preferred_element_type=jnp.float32)
@@ -48,7 +48,7 @@ def _kernel(x_ref, bits_ref, a_in_ref, a_out_ref, o_ref, *, bk, bn):
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _scale():
-        o_ref[...] = o_ref[...] * a_out_ref[...][None, :]
+        o_ref[...] = o_ref[...] * a_out_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -65,7 +65,8 @@ def binary_matmul(x: jax.Array, bits: jax.Array, alpha_out: jax.Array,
     n = bits.shape[1]
     if bits.shape[0] * 8 != kdim:
         raise ValueError(f"bits K span {bits.shape[0] * 8} != x K {kdim}")
-    bm, bn, bk = autotune.resolve_blocks(m, 0, kdim, n, bm, bn, bk)
+    bm, bn, bk = autotune.resolve_blocks(m, 0, kdim, n, bm, bn, bk,
+                                         tpu_tiling=not interpret)
     if bk is None or m % bm or n % bn or kdim % bk or bk % 8:
         raise ValueError(
             f"infeasible binary blocks (bm,bn,bk)=({bm},{bn},{bk}) for "
@@ -78,11 +79,12 @@ def binary_matmul(x: jax.Array, bits: jax.Array, alpha_out: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 8, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk,), lambda i, j, k: (k,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bk), lambda i, j, k: (0, k)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(x, bits, alpha_in.astype(jnp.float32), alpha_out.astype(jnp.float32))
+        name="binary_matmul", interpret=interpret,
+    )(x, bits, alpha_in.astype(jnp.float32).reshape(1, kdim),
+      alpha_out.astype(jnp.float32).reshape(1, n))
     return out.astype(x.dtype)

@@ -35,7 +35,7 @@ def _kernel(x_ref, w4_ref, s_ref, z_ref, o_ref, *, bk, bn):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = _unpack_nibbles_block(w4_ref[...], bk, bn)
-    w = (q - z_ref[...][:, None]) * s_ref[...][:, None]
+    w = (q - z_ref[...]) * s_ref[...]
     o_ref[...] += jax.lax.dot(x_ref[...].astype(jnp.bfloat16),
                               w.astype(jnp.bfloat16),
                               preferred_element_type=jnp.float32)
@@ -51,7 +51,7 @@ def int4_matmul(x: jax.Array, w4: jax.Array, s4: jax.Array, z4: jax.Array,
     if w4.shape[0] * 2 != kdim:
         raise ValueError(f"w4 K span {w4.shape[0] * 2} != x K {kdim}")
     bm, bn, bk = autotune.resolve_blocks(m, kdim, 0, n, bm, bn, bk,
-                                         align=2)
+                                         align=2, tpu_tiling=not interpret)
     if bk is None or m % bm or n % bn or kdim % bk or bk % 2:
         raise ValueError(
             f"infeasible int4 blocks (bm,bn,bk)=({bm},{bn},{bk}) for "
@@ -64,11 +64,12 @@ def int4_matmul(x: jax.Array, w4: jax.Array, s4: jax.Array, z4: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk,), lambda i, j, k: (k,)),
-            pl.BlockSpec((bk,), lambda i, j, k: (k,)),
+            pl.BlockSpec((bk, 1), lambda i, j, k: (k, 0)),
+            pl.BlockSpec((bk, 1), lambda i, j, k: (k, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(x, w4, s4.astype(jnp.float32), z4.astype(jnp.float32))
+        name="int4_matmul", interpret=interpret,
+    )(x, w4, s4.astype(jnp.float32).reshape(kdim, 1),
+      z4.astype(jnp.float32).reshape(kdim, 1))
     return out.astype(x.dtype)

@@ -47,18 +47,18 @@ def _body(x_tile, w4_ref, s_ref, z_ref, bits_ref, a_in_ref, a_out_ref,
     @pl.when(k < k4_steps)
     def _int4():
         q = _unpack_nibbles_block(w4_ref[...], bk, bn)
-        w = (q - z_ref[...][:, None]) * s_ref[...][:, None]
+        w = (q - z_ref[...]) * s_ref[...]
         o_ref[...] += jax.lax.dot(x_tile.astype(jnp.bfloat16),
                                   w.astype(jnp.bfloat16),
                                   preferred_element_type=jnp.float32)
 
     @pl.when(k >= k4_steps)
     def _binary():
-        x = x_tile.astype(jnp.float32) * a_in_ref[...][None, :]
+        x = x_tile.astype(jnp.float32) * a_in_ref[...]
         sign = _unpack_bits_block(bits_ref[...], bk, bn)
         acc = jax.lax.dot(x.astype(jnp.bfloat16), sign,
                           preferred_element_type=jnp.float32)
-        o_ref[...] += acc * a_out_ref[...][None, :]
+        o_ref[...] += acc * a_out_ref[...]
 
 
 def _kernel(x_ref, w4_ref, s_ref, z_ref, bits_ref, a_in_ref, a_out_ref,
@@ -105,7 +105,8 @@ def mixed_matmul(x: jax.Array, w4: jax.Array, s4: jax.Array, z4: jax.Array,
     if k_s + k_b != kdim:
         raise ValueError(f"k_s+k_b={k_s}+{k_b} != x K {kdim}")
     bm, bn, bk = autotune.resolve_blocks(m, k_s, k_b, n, bm, bn, bk,
-                                         bk_default=128)
+                                         bk_default=128,
+                                         tpu_tiling=not interpret)
     if bk is None or m % bm or n % bn or bk % 8:
         raise ValueError(
             f"infeasible mixed blocks (bm,bn,bk)=({bm},{bn},{bk}) for "
@@ -120,22 +121,27 @@ def mixed_matmul(x: jax.Array, w4: jax.Array, s4: jax.Array, z4: jax.Array,
         return (jnp.minimum(k, max(k4_steps - 1, 0)), j)
 
     def sz_map(i, j, k):
-        return (jnp.minimum(k, max(k4_steps - 1, 0)),)
+        return (jnp.minimum(k, max(k4_steps - 1, 0)), 0)
 
     def bits_map(i, j, k):
         return (jnp.clip(k - k4_steps, 0, max(kb_steps - 1, 0)), j)
 
     def ain_map(i, j, k):
-        return (jnp.clip(k - k4_steps, 0, max(kb_steps - 1, 0)),)
+        return (0, jnp.clip(k - k4_steps, 0, max(kb_steps - 1, 0)))
 
-    operands = (x, w4, s4.astype(jnp.float32), z4.astype(jnp.float32), bits,
-                alpha_in.astype(jnp.float32), alpha_out.astype(jnp.float32))
+    # per-K scales ride as (K, 1) columns and per-N / binary-K scales as
+    # (1, ·) rows: Mosaic only accepts 2-D blocks that match XLA's tiled
+    # layout, which 1-D (bk,) slices of a longer vector do not
+    operands = (x, w4, s4.astype(jnp.float32).reshape(k_s, 1),
+                z4.astype(jnp.float32).reshape(k_s, 1), bits,
+                alpha_in.astype(jnp.float32).reshape(1, k_b),
+                alpha_out.astype(jnp.float32).reshape(1, n))
     kern = functools.partial(
         _kernel if perm is None else _kernel_gather,
         bk=bk, bn=bn, k4_steps=k4_steps)
     out_spec_args = dict(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret)
+        name="mixed_matmul", interpret=interpret)
     if perm is None:
         in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))]
         tail = lambda f: f                      # 3-arg index maps as-is
@@ -148,11 +154,11 @@ def mixed_matmul(x: jax.Array, w4: jax.Array, s4: jax.Array, z4: jax.Array,
         out_map = lambda i, j, k, p: (i, j)
     in_specs += [
         pl.BlockSpec((bk // 2, bn), tail(w4_map)),
-        pl.BlockSpec((bk,), tail(sz_map)),
-        pl.BlockSpec((bk,), tail(sz_map)),
+        pl.BlockSpec((bk, 1), tail(sz_map)),
+        pl.BlockSpec((bk, 1), tail(sz_map)),
         pl.BlockSpec((bk // 8, bn), tail(bits_map)),
-        pl.BlockSpec((bk,), tail(ain_map)),
-        pl.BlockSpec((bn,), tail(lambda i, j, k: (j,))),
+        pl.BlockSpec((1, bk), tail(ain_map)),
+        pl.BlockSpec((1, bn), tail(lambda i, j, k: (0, j))),
     ]
     if perm is None:
         out = pl.pallas_call(

@@ -1,8 +1,11 @@
 """Jit'd public wrappers dispatching QLinear forwards to Pallas kernels.
 
-On CPU (this container) kernels run with ``interpret=True`` for
-correctness; on TPU set ``repro.kernels.ops.INTERPRET = False`` (the
-launcher does this when ``jax.default_backend() == 'tpu'``).
+``INTERPRET`` is fixed when this module is imported: False when
+``jax.default_backend()`` is a TPU, so the kernels compile through
+Mosaic, and True on any other backend, where every kernel runs in the
+Pallas interpreter (correct, and slow).  Nothing else sets it; tests
+that need the TPU-side gates monkeypatch it.  ``chip_smoke.py`` refuses
+to run with it True.
 
 Decode fast path notes (§Perf):
 
@@ -10,7 +13,8 @@ Decode fast path notes (§Perf):
   an unaligned-shape call falls back to the XLA dequant path without
   paying a dead (M, K) gather first.
 * Block sizes come from the :mod:`repro.kernels.autotune` cost model
-  (memoized per shape — the dispatch cache), not fixed constants: decode
+  (memoized per shape — the dispatch cache; Mosaic's tiling floors
+  apply off interpret mode), not fixed constants: decode
   calls at M = n_slots get M-sized row blocks and, VMEM permitting, a
   whole-N column block so the activation streams HBM→VMEM once per call.
 * ``pre_permuted=True`` skips the gather entirely for callers that
@@ -39,7 +43,7 @@ def _kernel_choice(m: int, k_s: int, k_b: int, n: int):
     the kernel's block specs need at least one step on each span)."""
     if k_s <= 0 or k_b <= 0:
         return None
-    return autotune.choose_blocks(m, k_s, k_b, n)
+    return autotune.choose_blocks(m, k_s, k_b, n, tpu_tiling=not INTERPRET)
 
 
 def mixed_matmul(x: jax.Array, q, *, pre_permuted: bool = False) -> jax.Array:
@@ -71,9 +75,9 @@ def mixed_matmul(x: jax.Array, q, *, pre_permuted: bool = False) -> jax.Array:
         xp = xf
     elif INTERPRET and autotune.gather_in_kernel_ok(choice, m, k):
         # gather moves into the kernel (scalar-prefetched perm).  Pinned
-        # to interpret mode for now: the dynamic lane-dim jnp.take over
-        # SMEM-sliced indices is unvalidated under Mosaic lowering — on
-        # a real TPU the host-side gather below stays until it is.
+        # to interpret mode: Mosaic refuses the vector slice of the
+        # SMEM perm ("Can only load scalars from SMEM"), so on a TPU the
+        # host-side gather below stays.
         xp, perm = xf, q.perm
     else:
         xp = jnp.take(xf, q.perm, axis=-1)
@@ -85,7 +89,7 @@ def mixed_matmul(x: jax.Array, q, *, pre_permuted: bool = False) -> jax.Array:
     return y.reshape(lead + (q.n,)).astype(x.dtype)
 
 
-LANE = 128      # TPU register-tile lane width (last-dim tiling floor)
+LANE = autotune.LANE    # TPU register-tile lane width (last-dim floor)
 
 
 def padded_head_dim(dh: int) -> int:
@@ -116,7 +120,8 @@ def paged_attention_blocks(ps: int, hkv: int, rep: int, dh: int,
     pool_dh = padded_head_dim(dh) if pool_dh is None else pool_dh
     if pool_dh < dh:
         return None
-    if not INTERPRET and (pool_dh % LANE != 0 or ps % 8 != 0):
+    if not INTERPRET and (pool_dh % LANE != 0
+                          or ps % autotune.SUBLANE != 0):
         return None
     return autotune.choose_paged_blocks(hkv, rep, pool_dh, ps)
 
@@ -143,7 +148,8 @@ def paged_prefill_blocks(c: int, ps: int, hkv: int, rep: int, dh: int,
     pool_dh = padded_head_dim(dh) if pool_dh is None else pool_dh
     if pool_dh < dh or c % ps:
         return None
-    if not INTERPRET and (pool_dh % LANE != 0 or ps % 8 != 0):
+    if not INTERPRET and (pool_dh % LANE != 0
+                          or ps % autotune.SUBLANE != 0):
         return None
     return autotune.choose_prefill_blocks(c, hkv, rep, pool_dh, ps)
 

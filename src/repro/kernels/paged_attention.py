@@ -247,7 +247,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                           window=window, softcap=softcap),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, dh_pool), jnp.float32),
-        interpret=interpret,
+        name="paged_attention", interpret=interpret,
     )(block_tables.reshape(-1).astype(jnp.int32),
       context_lens.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(b, hq, dh_pool)[..., :dh]

@@ -267,7 +267,7 @@ def paged_prefill(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         # operand numbering includes the scalar-prefetch args: the pools
         # (inputs 6/7) alias outputs 1/2 so chunk pages update in place
         input_output_aliases={6: 1, 7: 2},
-        interpret=interpret,
+        name="paged_prefill", interpret=interpret,
     )(bt_read.astype(jnp.int32), bt_write.astype(jnp.int32), meta,
       qg, knt, vnt, k_pool, v_pool)
     o = o.transpose(2, 0, 1, 3).reshape(c, hq, dhp)[..., :dh]

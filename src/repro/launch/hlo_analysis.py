@@ -528,6 +528,22 @@ def module_analysis(hlo: str) -> Dict:
     }
 
 
+_TPU_KERNEL_RE = re.compile(
+    r'^\s*(?:ROOT\s+)?%(?P<name>[\w\-]+?)(?:\.\d+)?\s*=.*'
+    r'custom_call_target="tpu_custom_call"', re.M)
+
+
+def tpu_kernels(hlo: str) -> Dict[str, int]:
+    """Pallas kernels in compiled TPU HLO: ``{name: call sites}``, where
+    ``name`` is the instruction's base name — a ``pallas_call``'s
+    ``name=`` (``mixed_matmul``, ``paged_attention``, …).  A kernel whose
+    caller fell back to its XLA path is simply absent."""
+    out: Dict[str, int] = defaultdict(int)
+    for m in _TPU_KERNEL_RE.finditer(hlo):
+        out[m.group("name")] += 1
+    return dict(out)
+
+
 def collective_summary(hlo: str) -> Dict:
     """Back-compat wrapper: just the collective block of module_analysis."""
     return module_analysis(hlo)["collectives"]

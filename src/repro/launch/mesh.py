@@ -12,27 +12,19 @@ outer data-parallel ring — cross-pod traffic is gradient all-reduce only
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across versions: 0.4.x has no ``axis_types`` kwarg
-    (Auto is the only behavior); newer jax wants it spelled explicitly."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Single-device mesh for smoke paths that still want `with mesh:`."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_devices(mesh) -> int:

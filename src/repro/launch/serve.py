@@ -101,7 +101,11 @@ def _drive(engine: Engine, *, stream: bool, cancel_after_s=None):
     return cancelled
 
 
-def run(args):
+def quantize_model(args):
+    """Build ``args.arch`` from a seed and quantize it as ``args.quantize``
+    says.  Returns ``(cfg, par, qparams, quantize_s)``; the fp weights
+    are dropped as soon as the packed ones exist, so the device holds
+    one copy of the model from here on."""
     cfg = registry.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -110,8 +114,6 @@ def run(args):
 
     qcfg = QuantConfig(ratio=args.ratio, multiple=args.multiple,
                        steps=args.opt_steps, use_kernel=args.kernel)
-    corpus = SyntheticCorpus(CorpusConfig(vocab=cfg.vocab, seed=args.seed))
-
     t0 = time.time()
     if args.quantize == "none":
         qparams = params
@@ -119,6 +121,8 @@ def run(args):
         if args.fused:
             print("[warn] --fused ignored for calibrated quantization "
                   "(per-projection QLinears cannot be fused post-hoc)")
+        corpus = SyntheticCorpus(CorpusConfig(vocab=cfg.vocab,
+                                              seed=args.seed))
         calib = [{"tokens": jnp.asarray(t)} for t, _ in
                  corpus.batches(1, args.calib_seq, args.calib_segments,
                                 split="calib")]
@@ -128,6 +132,8 @@ def run(args):
         qparams = quantize_params_data_free(params, qcfg,
                                             min_dim=args.min_dim,
                                             fuse=args.fused)
+    del params
+    jax.block_until_ready(qparams)
     t_quant = time.time() - t0
 
     if args.quantize != "none":
@@ -135,7 +141,11 @@ def run(args):
         print(f"[quant] {args.quantize} in {t_quant:.1f}s — "
               f"{rep['avg_bits_per_quantized_weight']:.3f} bits/weight over "
               f"{rep['quantized_weights']:,} weights")
+    return cfg, par, qparams, t_quant
 
+
+def build_engine(args, cfg, par, qparams) -> Engine:
+    """The serving engine the flags describe, over ``qparams``."""
     if args.share_prefix and not args.paged:
         raise SystemExit("--share-prefix requires --paged "
                          "(sharing lives in the page allocator)")
@@ -156,16 +166,26 @@ def run(args):
                     chunked_prefill=args.chunked_prefill,
                     prefill_chunk=args.prefill_chunk,
                     fuse_projections=args.fused and args.quantize == "none")
+    for c in _classes(args):
+        if not engine.scheduler.has_class(c):
+            raise SystemExit(f"unknown priority class {c!r}; configured: "
+                             f"{sorted(engine.scheduler.cfg.class_weights)}")
+    return engine
 
+
+def _classes(args):
     classes = [c.strip() for c in args.priority.split(",") if c.strip()]
     if not classes:
         raise SystemExit("--priority needs at least one class name "
                          "(e.g. --priority realtime,batch)")
-    for c in classes:
-        if not engine.scheduler.has_class(c):
-            raise SystemExit(f"unknown priority class {c!r}; configured: "
-                             f"{sorted(engine.scheduler.cfg.class_weights)}")
+    return classes
 
+
+def submit_requests(args, engine: Engine):
+    """Submit ``args.requests`` synthetic prompts (seeded) to ``engine``."""
+    corpus = SyntheticCorpus(CorpusConfig(vocab=engine.cfg.vocab,
+                                          seed=args.seed))
+    classes = _classes(args)
     rng = np.random.default_rng(args.seed)
     # --share-prefix: a page-aligned common document prefix (half the
     # prompt budget) + per-request unique tails — the sharing workload
@@ -184,7 +204,12 @@ def run(args):
                                   temperature=args.temperature,
                                   deadline_s=args.deadline_s,
                                   priority=classes[i % len(classes)]))
+    return reqs
 
+
+def serve(args, engine: Engine, reqs, t_quant: float):
+    """Drive ``engine`` until ``reqs`` drain; print and return the
+    result JSON."""
     t0 = time.time()
     if args.stream or args.cancel_after_s is not None:
         cancelled = _drive(engine, stream=args.stream,
@@ -201,7 +226,7 @@ def run(args):
         "tokens_per_s": toks / max(dt, 1e-9),
         "all_done": all(r.done for r in reqs),
         "cancelled": cancelled,
-        "priority_classes": classes,
+        "priority_classes": _classes(args),
         "quantize_mode": args.quantize,
         "quantize_s": t_quant,
         "cache_backend": engine.backend.name,
@@ -213,6 +238,12 @@ def run(args):
         with open(args.json_out, "w") as f:
             json.dump(out, f, indent=2)
     return out
+
+
+def run(args):
+    cfg, par, qparams, t_quant = quantize_model(args)
+    engine = build_engine(args, cfg, par, qparams)
+    return serve(args, engine, submit_requests(args, engine), t_quant)
 
 
 def parse_args(argv=None):
@@ -228,7 +259,9 @@ def parse_args(argv=None):
                         "path): fused packed layouts for data-free "
                         "quantization, fp concat fusion for --quantize none")
     p.add_argument("--ratio", type=float, default=0.2)
-    p.add_argument("--multiple", type=int, default=16)
+    p.add_argument("--multiple", type=int, default=128,
+                   help="salient-span rounding; 128 keeps both K spans "
+                        "on the TPU lane tiling the packed kernel needs")
     p.add_argument("--min-dim", type=int, default=32)
     p.add_argument("--opt-steps", type=int, default=3)
     p.add_argument("--calib-segments", type=int, default=4)
@@ -285,4 +318,6 @@ def parse_args(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     run(parse_args())

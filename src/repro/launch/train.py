@@ -268,4 +268,6 @@ def parse_args(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     run(parse_args())

@@ -53,19 +53,6 @@ class Parallel:
         return best
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: `jax.shard_map(..., check_vma=)` on
-    new jax, `jax.experimental.shard_map.shard_map(..., check_rep=)` on
-    0.4.x.  Replication checking is off in both (the MoE/pipeline bodies
-    use collectives the checker can't type)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def current_mesh():
     """The ambient mesh during tracing, or None.
 
@@ -75,11 +62,9 @@ def current_mesh():
     mesh alone is empty under ``with mesh:``, which silently no-ops every
     activation hint (found via the dry-run roofline; EXPERIMENTS.md §Perf).
     """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:          # jax >= 0.5; absent on 0.4.x
-        am = get_am()
-        if am is not None and not am.empty:
-            return am
+    am = jax.sharding.get_abstract_mesh()
+    if am is not None and not am.empty:
+        return am
     try:
         from jax._src import mesh as mesh_lib
         m = mesh_lib.thread_resources.env.physical_mesh

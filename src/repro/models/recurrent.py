@@ -447,13 +447,12 @@ def slstm_seq(cfg: ArchConfig, p: Tree, x: jax.Array,
         from jax.sharding import PartitionSpec as PS
         baxes = _batch_axes()
         st_spec = jax.tree.map(lambda _: PS(baxes, None), state)
-        from repro.models.common import shard_map_compat
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             functools.partial(_slstm_scan, cfg),
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: PS(), p_rec),
                       PS(baxes, None, None), st_spec),
-            out_specs=(st_spec, PS(baxes, None, None)))
+            out_specs=(st_spec, PS(baxes, None, None)), check_vma=False)
         state, hs = fn(p_rec, zx, state)
     else:
         state, hs = _slstm_scan(cfg, p_rec, zx, state)

@@ -298,6 +298,23 @@ class _PagedBackend:
                                            jnp.asarray(lens))
         return logits
 
+    def lowered_steps(self, params) -> Dict[str, Any]:
+        """The jitted decode and chunk-prefill steps lowered at the
+        argument shapes the tick loop passes (``jax.stages.Lowered``),
+        so a caller can compile them and see which kernels they hold."""
+        n = self.eng.n_slots
+        slots = jnp.zeros((n,), jnp.int32)
+        row = jnp.asarray(self.tables.as_array()[0])
+        return {
+            "decode": self._decode.lower(
+                params, slots, slots, self.caches,
+                jnp.asarray(self.tables.as_array()),
+                jnp.asarray(self.tables.context_lens())),
+            "prefill_chunk": self._chunk_step.lower(
+                params, jnp.zeros((1, self.eng.prefill_chunk), jnp.int32),
+                self.caches, row, row, jnp.int32(0), jnp.int32(1)),
+        }
+
     def prefill_chunk(self, params, toks, slot: int, start: int,
                       length: int):
         """Advance ``slot``'s prefill by one chunk: fused scatter+attend
@@ -522,6 +539,54 @@ class Engine:
         # positions — one extra prefill compile, no truncation)
         return self.max_seq
 
+    def _prefill_batch(self, seq: np.ndarray) -> Dict[str, jax.Array]:
+        """Whole-prompt prefill input: ``seq`` left-padded to its bucket.
+        Pad positions are -1: masked out of attention and never written
+        into KV storage (ring p=-1 / paged scatter drop)."""
+        s = len(seq)
+        b = self._bucket(s)
+        toks = np.full((1, b), 0, np.int32)
+        toks[0, -s:] = seq
+        idx = np.arange(b, dtype=np.int32)
+        positions = np.where(idx >= b - s, idx - (b - s), -1)[None]
+        return {"tokens": jnp.asarray(toks),
+                "positions": jnp.asarray(positions)}
+
+    def _chunk_tokens(self, seq: np.ndarray, start: int) -> jax.Array:
+        """One prefill chunk's (1, prefill_chunk) tokens from ``start``,
+        zero-padded past the prompt end."""
+        c = self.prefill_chunk
+        length = min(c, len(seq) - start)
+        toks = np.zeros((1, c), np.int32)
+        toks[0, :length] = seq[start:start + length]
+        return jnp.asarray(toks)
+
+    def prefill_logits(self, prompt: np.ndarray) -> np.ndarray:
+        """Last-position logits (V,) f32 of ``prompt`` through this
+        engine's own prefill program — chunk by chunk in a free slot
+        under chunked prefill, else the whole-prompt bucket — without
+        admitting a request.  For comparing one weight or kernel path
+        against another; the slot's pages are released again."""
+        seq = np.asarray(prompt, np.int32)
+        if not self.chunked_prefill:
+            logits, _ = self._prefill(self.params, self._prefill_batch(seq))
+            return np.asarray(logits[0, -1], np.float32)
+        be = self.backend
+        slot = self.slot_req.index(None)
+        if not be.tables.ensure_blocks(
+                slot, pages_for_tokens(len(seq), be.page_size)):
+            raise RuntimeError("no free pages for the prompt")
+        try:
+            for start in range(0, len(seq), self.prefill_chunk):
+                logits = be.prefill_chunk(
+                    self.params, self._chunk_tokens(seq, start), slot,
+                    start, min(self.prefill_chunk, len(seq) - start))
+            # read back BEFORE the release rewrites the block-table row:
+            # on CPU the in-flight step may alias that numpy row
+            return np.asarray(logits[0], np.float32)
+        finally:
+            be.release(slot)
+
     def _context_seq(self, r: Request) -> np.ndarray:
         """The token sequence a (re-)prefill of ``r`` must cover — the
         prompt, plus for preemption resumes the already-generated tokens
@@ -605,12 +670,10 @@ class Engine:
         start = st["frontier"]
         c = self.prefill_chunk
         length = min(c, s - start)
-        toks = np.zeros((1, c), np.int32)
-        toks[0, :length] = seq[start:start + length]
+        toks = self._chunk_tokens(seq, start)
         logits = self._timed(
             "prefill_chunk", c,
-            lambda: self.backend.prefill_chunk(self.params,
-                                               jnp.asarray(toks), slot,
+            lambda: self.backend.prefill_chunk(self.params, toks, slot,
                                                start, length))
         st["frontier"] = start + length
         self.metrics.on_prefill_chunk(length)
@@ -681,14 +744,7 @@ class Engine:
         assert len(seq) <= self.max_seq - 1, (len(seq), self.max_seq)
         s = len(seq)
         b = self._bucket(s)
-        toks = np.full((1, b), 0, np.int32)
-        toks[0, -s:] = seq                       # left-pad
-        # pad positions are -1: masked out of attention and never written
-        # into KV storage (ring p=-1 / paged scatter drop)
-        idx = np.arange(b, dtype=np.int32)
-        positions = np.where(idx >= b - s, idx - (b - s), -1)[None]
-        batch = {"tokens": jnp.asarray(toks),
-                 "positions": jnp.asarray(positions)}
+        batch = self._prefill_batch(seq)
         logits, cache1 = self._timed(
             "prefill", b, lambda: self._prefill(self.params, batch))
         be = self.backend

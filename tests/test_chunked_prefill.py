@@ -157,6 +157,44 @@ def test_chunked_matches_whole_prompt_logits(subject, plen):
     assert int(jnp.argmax(logits)) == int(jnp.argmax(ref_logits[:, 0]))
 
 
+@pytest.mark.parametrize("plen", [5, 23, 61])
+def test_engine_prefill_logits_chunked_matches_whole(subject, plen):
+    """Engine.prefill_logits runs the engine's own prefill program: the
+    chunked engine (several chunks) and the whole-prompt engine agree on
+    an all-f32 model, and the chunked scratch slot returns its pages."""
+    cfg, params = subject
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.float32)
+        if isinstance(a, jax.Array) and a.dtype == jnp.bfloat16 else a,
+        params)
+    seq = np.random.default_rng(plen).integers(
+        1, cfg.vocab, size=plen).astype(np.int32)
+    kw = dict(n_slots=2, max_seq=128, prefill_buckets=(64, 128),
+              paged=True, page_size=8, cache_dtype=jnp.float32)
+    whole = Engine(cfg, PAR, params, **kw).prefill_logits(seq)
+    eng = Engine(cfg, PAR, params, chunked_prefill=True, prefill_chunk=16,
+                 **kw)
+    chunked = eng.prefill_logits(seq)
+    assert whole.shape == chunked.shape == (cfg.vocab_padded,)
+    np.testing.assert_allclose(chunked, whole, rtol=2e-5, atol=2e-5)
+    assert eng.backend.pool.free_pages == eng.backend.pool.num_pages
+    assert not eng.has_work
+
+
+def test_engine_lowered_steps_shapes(subject):
+    """The lowered decode / chunk-prefill steps are the engine's own
+    programs at its live shapes: they compile and return (n_slots, V)
+    and (1, V) logits."""
+    cfg, params = subject
+    eng = Engine(cfg, PAR, params, n_slots=3, max_seq=64, paged=True,
+                 page_size=8, chunked_prefill=True, prefill_chunk=16)
+    steps = eng.backend.lowered_steps(eng.params)
+    assert set(steps) == {"decode", "prefill_chunk"}
+    logits = {k: v.compile().out_info[0] for k, v in steps.items()}
+    assert logits["decode"].shape == (3, cfg.vocab)
+    assert logits["prefill_chunk"].shape == (1, cfg.vocab)
+
+
 def test_engine_chunked_vs_whole_greedy_identity(subject):
     """Engine-level: ragged prompts, f32 pools — greedy outputs of the
     chunked engine are bit-identical to the whole-prompt engine's."""

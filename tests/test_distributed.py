@@ -4,12 +4,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as PS
 
 from repro.distributed.compression import (CompressionConfig, compress,
                                            init_residual)
 from repro.distributed.sharding import Rules
-from repro.launch.mesh import compat_make_mesh
 from repro.models.param import P
 
 
@@ -103,7 +103,7 @@ def test_pipeline_matches_sequential(rng):
 def test_pipeline_single_stage_oracle(rng):
     """n_stages=1 degenerate ring equals plain application."""
     from repro.distributed.pipeline import pipeline_apply
-    mesh = compat_make_mesh((1,), ("stage",))
+    mesh = jax.make_mesh((1,), ("stage",), axis_types=(AxisType.Auto,))
     w = jnp.asarray(rng.normal(size=(1, 8, 8)), jnp.float32)
     x = jnp.asarray(rng.normal(size=(3, 4, 8)), jnp.float32)
 
@@ -144,11 +144,29 @@ def test_hlo_flop_count_scan_vs_unroll():
     mod = H.module_analysis(c.as_text())
     expect = 2 * 32 * D * D * L * MB * 3       # fwd + dgrad + wgrad
     assert abs(mod["flops"] - expect) / expect < 0.05
-    ca = c.cost_analysis()          # dict on new jax, [dict] on 0.4.x
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    xla = float(ca.get("flops", 0.0))
+    xla = float(c.cost_analysis().get("flops", 0.0))
     assert xla < 0.5 * expect                  # XLA's known undercount
+
+
+def test_tpu_kernels_inventory_fixture():
+    """Pallas kernels are found by their instruction base name on
+    tpu_custom_call sites only (other custom calls do not count)."""
+    from repro.launch import hlo_analysis as H
+    hlo = """
+HloModule test
+
+%body (p: f32[4,2048]) -> f32[4,2048] {
+  ROOT %mixed_matmul.12 = f32[4,2048]{1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call", backend_config={"custom_call_config": {}}
+}
+
+ENTRY %main (a: bf16[4,2048]) -> f32[4,2048] {
+  %mixed_matmul.1 = f32[4,2048]{1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call"
+  %paged_attention = f32[4,2,8,128]{3,2,1,0} custom-call(%c), custom_call_target="tpu_custom_call"
+  %sharding.3 = f32[4,2048]{1,0} custom-call(%a), custom_call_target="Sharding"
+}
+"""
+    assert H.tpu_kernels(hlo) == {"mixed_matmul": 2, "paged_attention": 1}
+    assert H.tpu_kernels("HloModule empty") == {}
 
 
 def test_hlo_collective_parsing_fixture():

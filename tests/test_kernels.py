@@ -220,6 +220,36 @@ def test_autotune_knobs_are_live():
     assert tight is None or tight.vmem_bytes <= 1 << 20
 
 
+@pytest.mark.parametrize("m", [4, 64], ids=["decode", "chunk"])
+@pytest.mark.parametrize("k,n", [(2048, 2560), (2048, 2048), (2048, 22016),
+                                 (11008, 2048)])
+def test_autotune_tpu_tiling_floors(m, k, n):
+    """Qwen2.5-3B's packed projections at 128-channel salient spans:
+    the Mosaic-side pick keeps bk on the 128-lane tiling, bm at all of M
+    or a multiple of 8, and the counted VMEM inside the budget."""
+    from repro.core.saliency import round_salient
+    from repro.kernels import autotune
+    k_s = round_salient(k, 0.2, 128)
+    c = autotune.choose_blocks(m, k_s, k - k_s, n, tpu_tiling=True)
+    assert c is not None
+    assert c.bk % autotune.LANE == 0
+    assert k_s % c.bk == 0 and (k - k_s) % c.bk == 0
+    assert c.bm == m or c.bm % autotune.SUBLANE == 0
+    assert n % c.bn == 0 and c.bn % autotune.LANE == 0
+    assert c.vmem_bytes <= autotune.VMEM_BUDGET
+
+
+def test_autotune_counts_unpack_temporaries():
+    """A whole-N gate+up block (bn=22016, bk=128) needs ~41 MB of scoped
+    VMEM under Mosaic — the footprint model must price it out of a
+    16 MiB limit, not pass it for its ~4 MB of pipeline buffers."""
+    from repro.kernels import autotune
+    assert autotune.kernel_vmem_bytes(4, 22016, 128) > 16 * 1024 * 1024
+    # spans with no 128-multiple common divisor cannot tile for Mosaic
+    assert autotune.choose_blocks(4, 128, 192, 256, tpu_tiling=True) is None
+    assert autotune.choose_blocks(4, 128, 192, 256) is not None
+
+
 def test_autotune_unfeasible_shapes():
     from repro.kernels import autotune
     assert autotune.choose_blocks(4, 128, 512, 200) is None   # N % 128

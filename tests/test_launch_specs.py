@@ -128,3 +128,23 @@ def test_presets_cover_all_cells():
             assert p.par.tp == 16
             assert p.par.microbatches >= 1
             assert p.par.remat == (cell.kind == "train")
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """Entry points keep the persistent compile cache where
+    $JAX_COMPILATION_CACHE_DIR says (setting nothing), else at the fixed
+    in-repo .jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable()
+        assert path == str(compile_cache.REPO_CACHE_DIR)
+        assert path.endswith(".jax_cache")
+        assert (compile_cache.REPO_CACHE_DIR.parent / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -370,3 +370,43 @@ def test_paged_metrics_sanity(subject, rng):
     assert snap["ttft_mean_s"] > 0 and snap["tokens_per_s"] > 0
     assert 0 < snap["page_util_max"] <= 1.0
     assert snap["completed"] == 1
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py — its checks rehearsed at CPU scale
+# ---------------------------------------------------------------------------
+def _chip_smoke():
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_refuses_cpu(capsys):
+    """Without a TPU the smoke exits non-zero and never prints ok."""
+    assert jax.devices()[0].platform != "tpu"
+    assert _chip_smoke().main() != 0
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_chip_smoke_checks_at_cpu_scale():
+    """The smoke's own pipeline — serve launcher pieces, the chunked
+    paged engine with packed kernels (interpret mode here), all tokens
+    served, kernel-vs-XLA logits within LOGIT_TOL — on the reduced
+    Qwen2.5-3B.  Interpret mode compiles no tpu_custom_call, so the
+    kernel inventory check must refuse what it finds here."""
+    smoke = _chip_smoke()
+    argv = ["--arch", "qwen2.5-3b", "--reduced", "--multiple", "16",
+            "--quantize", "datafree", "--kernel", "--fused", "--paged",
+            "--chunked-prefill", "--page-size", "16", "--prefill-chunk",
+            "32", "--requests", "3", "--slots", "2", "--max-seq", "256",
+            "--max-new", "4", "--seed", "0"]
+    out = smoke.run_smoke(argv)
+    assert out["generated_tokens"] == 3 * 4
+    assert 0.0 <= out["logit_rel_diff"] <= smoke.LOGIT_TOL
+    assert out["kernels"] == {step: {} for step in smoke.STEP_KERNELS}
+    with pytest.raises(smoke.SmokeFailure, match="fell back to XLA"):
+        smoke.check_kernels(out["kernels"])

@@ -6,13 +6,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import registry
 from repro.core import pipeline
 from repro.core.qlinear import (QLinearGroup, QuantConfig, quantize_linear,
                                 quantize_linear_group)
 from repro.kernels import ops
-from repro.launch.mesh import compat_make_mesh
 from repro.models import layers as L
 from repro.models import model as M
 from repro.models import recurrent as R
@@ -70,7 +70,8 @@ def test_moe_shard_map_matches_fallback():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(2, 16, cfg.d_model)) * 0.3,
                     jnp.float32)
-    mesh = compat_make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     par = Parallel(tp=2, dp=2, remat=False, attn_chunk=32)
 
     def loss(p, use_par):
