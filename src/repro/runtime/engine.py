@@ -35,7 +35,11 @@ themselves and drain the queue in between.  :meth:`Engine.cancel`
 aborts a request wherever it is — queued requests leave the scheduler,
 in-flight requests give their slot and pages back **in the same tick**
 (the ``FinishEvent(reason="cancelled")`` carries the freed page count
-as the receipt).
+as the receipt).  Each tick names its host work in profiler spans
+(``engine.tick`` and the phases inside it, :mod:`repro.runtime.tracing`),
+recorded whenever a ``jax.profiler`` session runs, and folds the XLA
+compiles and garbage collections that happened inside it into the
+metrics.
 
 Two cache backends behind one interface:
 
@@ -78,12 +82,14 @@ from repro.models import model as M
 from repro.models import transformer as T
 from repro.models.common import Parallel
 from repro.models.param import materialize
+from repro.runtime import tracing
 from repro.runtime.events import (EventBus, ExpireEvent, FinishEvent,
                                   PreemptEvent, TokenEvent)
 from repro.runtime.metrics import EngineMetrics
 from repro.runtime.paged_cache import (BlockTables, PagePool, PrefixCache,
                                        pages_for_tokens)
 from repro.runtime.scheduler import DEFAULT_CLASS, Scheduler
+from repro.runtime.tracing import span
 
 Tree = Any
 
@@ -399,6 +405,9 @@ class Engine:
         self.scheduler = scheduler or Scheduler()
         self.metrics = metrics or EngineMetrics()
         self.events = EventBus()
+        tracing.install()
+        # runtime counters at the last look (tick start, before tokens)
+        self._seen = tracing.counters()
 
         self.slot_req: List[Optional[Request]] = [None] * n_slots
         self.pos = np.zeros((n_slots,), np.int32)
@@ -670,21 +679,23 @@ class Engine:
         start = st["frontier"]
         c = self.prefill_chunk
         length = min(c, s - start)
-        toks = self._chunk_tokens(seq, start)
-        logits = self._timed(
-            "prefill_chunk", c,
-            lambda: self.backend.prefill_chunk(self.params, toks, slot,
-                                               start, length))
-        st["frontier"] = start + length
-        self.metrics.on_prefill_chunk(length)
-        # register the freshly-completed full pages as they appear (so
-        # cohort peers can catch up mid-prefill, not only after we
-        # finish); the chain state makes each call O(chunk)
-        if be.prefix is not None:
-            st["reg_state"], _ = be.prefix.register_prefix(
-                seq[:st["frontier"]], be.tables.owned(slot),
-                st.get("reg_state"))
-            st["match_ver"] = (be.prefix.writes, be.pool.free_events)
+        with span("engine.prefill_chunk", rid=r.rid, start=start,
+                  length=length):
+            toks = self._chunk_tokens(seq, start)
+            logits = self._timed(
+                "prefill_chunk", c,
+                lambda: self.backend.prefill_chunk(self.params, toks, slot,
+                                                   start, length))
+            st["frontier"] = start + length
+            self.metrics.on_prefill_chunk(length)
+            # register the freshly-completed full pages as they appear
+            # (so cohort peers can catch up mid-prefill, not only after
+            # we finish); the chain state makes each call O(chunk)
+            if be.prefix is not None:
+                st["reg_state"], _ = be.prefix.register_prefix(
+                    seq[:st["frontier"]], be.tables.owned(slot),
+                    st.get("reg_state"))
+                st["match_ver"] = (be.prefix.writes, be.pool.free_events)
         if st["frontier"] < s:
             return length
         # ---- prompt complete: graduate to decoding -------------------
@@ -704,10 +715,14 @@ class Engine:
         if st["resumed"]:
             tok = r.out_tokens[-1]
         else:
-            tok = int(self._sample(logits.astype(jnp.float32),
-                                   self._next_key(),
-                                   jnp.asarray([r.temperature],
-                                               jnp.float32))[0])
+            with span("engine.sample"):
+                sampled = self._sample(logits.astype(jnp.float32),
+                                       self._next_key(),
+                                       jnp.asarray([r.temperature],
+                                                   jnp.float32))
+            with span("engine.readback"):
+                tok = int(np.asarray(sampled)[0])
+            self._note_runtime()
             r.out_tokens.append(tok)
             self.metrics.on_token(r.rid)
             self._emit(TokenEvent(r.rid, tok, len(r.out_tokens) - 1,
@@ -765,6 +780,7 @@ class Engine:
                                    self._next_key(),
                                    jnp.asarray([r.temperature],
                                                jnp.float32))[0])
+            self._note_runtime()
             r.out_tokens.append(tok)
             self.metrics.on_token(r.rid)
             self._emit(TokenEvent(r.rid, tok, len(r.out_tokens) - 1,
@@ -937,17 +953,37 @@ class Engine:
         prefill in the same tick."""
         self._tick_no += 1
         self._in_tick = True
-        try:
-            return self._tick_body()
-        finally:
-            self._in_tick = False
-            pending, self._pending_cancels = self._pending_cancels, []
-            for rid in pending:          # deferred from event callbacks:
-                self._do_cancel(rid)     # still "the same tick"
+        self._seen = tracing.counters()
+        with span("engine.tick"):
+            try:
+                return self._tick_body()
+            finally:
+                self._in_tick = False
+                pending, self._pending_cancels = self._pending_cancels, []
+                for rid in pending:          # deferred from event callbacks:
+                    self._do_cancel(rid)     # still "the same tick"
+                self._note_runtime()
+
+    def _note_runtime(self) -> None:
+        """Fold the XLA compiles and garbage collections since the last
+        look into the metrics.  Called before tokens are counted, so an
+        inter-token gap that holds a compile is a stall and stays out of
+        the TBT series, whether or not phases are timed."""
+        compiles, collections, pauses = now = tracing.counters()
+        seen_compiles, seen_collections, seen_pauses = self._seen
+        self._seen = now
+        if compiles != seen_compiles:
+            self.metrics.on_compiles(compiles - seen_compiles)
+        if collections != seen_collections:
+            self.metrics.on_gc(
+                [a - b for a, b in zip(collections, seen_collections)],
+                [a - b for a, b in zip(pauses, seen_pauses)])
 
     def _tick_body(self) -> bool:
-        self._grow_caches()
-        self._admit()
+        with span("engine.grow"):
+            self._grow_caches()
+        with span("engine.admit"):
+            self._admit()
         if all(r is None for r in self.slot_req):
             return False
         self.metrics.on_tick(
@@ -971,45 +1007,51 @@ class Engine:
                     if r is not None and s not in self._prefill_state]
         if not decoding:
             return True                 # pure-prefill tick
-        active = None
-        if self._prefill_state:
-            active = np.zeros((self.n_slots,), bool)
-            active[decoding] = True
-        toks = jnp.asarray(self.cur_tok)
-        pos = jnp.asarray(self.pos)
-        logits = self._timed(
-            "decode", self.backend.name,
-            lambda: (self.backend.decode(self.params, toks, pos, active)
-                     if active is not None else
-                     self.backend.decode(self.params, toks, pos)))
+        with span("engine.decode"):
+            active = None
+            if self._prefill_state:
+                active = np.zeros((self.n_slots,), bool)
+                active[decoding] = True
+            toks = jnp.asarray(self.cur_tok)
+            pos = jnp.asarray(self.pos)
+            logits = self._timed(
+                "decode", self.backend.name,
+                lambda: (self.backend.decode(self.params, toks, pos, active)
+                         if active is not None else
+                         self.backend.decode(self.params, toks, pos)))
         # one vectorized device sample across all slots (no per-slot
-        # logits round-trips through numpy)
-        next_toks = np.asarray(self._sample(logits.astype(jnp.float32),
-                                            self._next_key(),
-                                            jnp.asarray(self.temps)))
-        for slot, r in enumerate(self.slot_req):
-            if r is None or slot in self._prefill_state:
-                continue
-            tok = int(next_toks[slot])
-            r.out_tokens.append(tok)
-            self.metrics.on_token(r.rid)
-            self.pos[slot] += 1
-            self.cur_tok[slot] = tok
-            self._emit(TokenEvent(r.rid, tok, len(r.out_tokens) - 1,
-                                  self._tick_no))
-            # a cancel issued from an event callback is DEFERRED (see
-            # tick()'s finally), so r.done cannot flip under this loop
-            if len(r.out_tokens) >= r.max_new or \
-                    self.pos[slot] >= self.max_seq - 1:
-                reason = ("max_new" if len(r.out_tokens) >= r.max_new
-                          else "max_seq")
-                r.done = True
-                self.metrics.on_finish(r.rid)
-                self._requests.pop(r.rid, None)
-                freed = self.backend.release(slot)
-                self.slot_req[slot] = None
-                self._emit(FinishEvent(r.rid, reason, len(r.out_tokens),
-                                       freed, self._tick_no))
+        # logits round-trips through numpy); dispatch and wait apart
+        with span("engine.sample"):
+            sampled = self._sample(logits.astype(jnp.float32),
+                                   self._next_key(), jnp.asarray(self.temps))
+        with span("engine.readback"):
+            next_toks = np.asarray(sampled)
+        with span("engine.emit"):
+            self._note_runtime()
+            for slot, r in enumerate(self.slot_req):
+                if r is None or slot in self._prefill_state:
+                    continue
+                tok = int(next_toks[slot])
+                r.out_tokens.append(tok)
+                self.metrics.on_token(r.rid)
+                self.pos[slot] += 1
+                self.cur_tok[slot] = tok
+                self._emit(TokenEvent(r.rid, tok, len(r.out_tokens) - 1,
+                                      self._tick_no))
+                # a cancel issued from an event callback is DEFERRED (see
+                # tick()'s finally), so r.done cannot flip under this loop
+                if len(r.out_tokens) >= r.max_new or \
+                        self.pos[slot] >= self.max_seq - 1:
+                    reason = ("max_new" if len(r.out_tokens) >= r.max_new
+                              else "max_seq")
+                    r.done = True
+                    self.metrics.on_finish(r.rid)
+                    self._requests.pop(r.rid, None)
+                    freed = self.backend.release(slot)
+                    self.slot_req[slot] = None
+                    self._emit(FinishEvent(r.rid, reason,
+                                           len(r.out_tokens), freed,
+                                           self._tick_no))
         return True
 
     # back-compat alias: tick() is the reentrant primitive

@@ -5,8 +5,11 @@ engine reports lifecycle events (submit / admit / first token / finish /
 preempt / expire / cancel) and one gauge sample per decode tick;
 ``snapshot()`` reduces them to the serving numbers that matter — tokens/s,
 time-to-first-token, inter-token latency (TBT), queue depth, page
-utilization — and ``to_json()`` exports them for the benchmark harness
-(``benchmarks/serving_bench.py``).
+utilization — for ``launch/serve.py``'s JSON and
+``benchmarks/serving_bench.py``.  It also holds the host runtime's
+counters over the engine's ticks (:mod:`repro.runtime.tracing`): XLA
+compiles, which are stalls, and garbage collections with their pauses
+by generation.
 
 Now that the engine emits every token through the event bus the tick it
 is sampled, **inter-token latency is observable per request**: every
@@ -21,7 +24,7 @@ The clock is injectable so tests can drive deterministic time.
 """
 from __future__ import annotations
 
-import json
+import gc
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
@@ -65,6 +68,11 @@ class EngineMetrics:
         self.prefill_chunks = 0
         self.prefill_chunk_tokens = 0
         self.prefill_tokens_skipped = 0
+        # host runtime inside the engine's ticks: XLA compiles, and
+        # garbage collections and their pauses by generation
+        self.compiles = 0
+        self.gc_collections = [0] * len(gc.get_count())
+        self.gc_pause_s = [0.0] * len(gc.get_count())
         self._start_t: Optional[float] = None
         self._last_t: Optional[float] = None
         # per-tick gauge samples
@@ -109,6 +117,19 @@ class EngineMetrics:
         inter-token gap of every in-flight request is not decode
         latency and must not enter the TBT series."""
         self._stalls += 1
+
+    def on_compiles(self, n: int) -> None:
+        """``n`` XLA compiles happened inside a tick: a stall, whether or
+        not the engine times its phases."""
+        self.compiles += n
+        self.on_stall()
+
+    def on_gc(self, collections, pause_s) -> None:
+        """Garbage collections inside a tick and their pause seconds, by
+        generation."""
+        for g, (n, s) in enumerate(zip(collections, pause_s)):
+            self.gc_collections[g] += n
+            self.gc_pause_s[g] += s
 
     def on_finish(self, rid: int) -> None:
         self._req[rid].finish_t = self.clock()
@@ -211,6 +232,9 @@ class EngineMetrics:
             "preemptions": self.preemptions,
             "expirations": self.expirations,
             "cancellations": self.cancellations,
+            "compiles": self.compiles,
+            "gc_collections": list(self.gc_collections),
+            "gc_pause_s": list(self.gc_pause_s),
             "queue_depth_mean": mean(self.queue_depth),
             "queue_depth_max": max(self.queue_depth, default=0),
             "active_slots_mean": mean(self.active_slots),
@@ -227,10 +251,3 @@ class EngineMetrics:
                 } for phase, ts in sorted(self.phase_times.items())
             },
         }
-
-    def to_json(self, path: Optional[str] = None) -> str:
-        s = json.dumps(self.snapshot(), indent=2, default=float)
-        if path:
-            with open(path, "w") as f:
-                f.write(s)
-        return s
