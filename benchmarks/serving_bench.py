@@ -28,14 +28,14 @@ reference path (whole pool window per token) so the decode
 attention-traffic win is recorded next to it.  Each row carries
 ``kv_read_kb_per_tok``: for kernel rows this is MEASURED — every
 decode tick's (block_tables, context_lens) state is captured and the
-kernel's own K/V index map is replayed over the grid
-(``paged_attention.fetched_page_counts``, the same ``kv_block_index``
-the BlockSpec runs) to count the page DMAs actually issued; for
-XLA/contiguous rows it is the dense window the gather materializes.
-The sweep ASSERTS, per slot per tick, that the kernel's fetches stay
-≤ the slot's live tokens plus one page of slack — a live gate on the
-index-map clamp, not a restatement of the cost model: breaking the
-clamp (dead grid steps fetching fresh pages) fails the run.
+kernel's own fetch contract is replayed over it
+(``paged_attention.fetched_page_counts``, the same ``page_fetched``
+the kernel's DMA loop runs) to count the page DMAs actually started;
+for XLA/contiguous rows it is the dense window the gather
+materializes.  The sweep ASSERTS, per slot per tick, that the
+kernel's fetches stay ≤ the slot's live tokens plus one page of slack
+— a live gate on the fetch contract, not a restatement of the cost
+model: breaking it (dead pages fetched) fails the run.
 
 Event-loop scenarios (both run under ``--quick`` so CI's artifact
 carries their rows):
@@ -86,13 +86,13 @@ def kv_bytes(cfg, *, paged: bool, pool_pages: int = 0) -> int:
 
 def measured_kernel_read_kb_per_tok(cfg, tick_states) -> float:
     """MEASURED KV bytes per generated token through the flash-decode
-    kernel: replay the kernel's own K/V index map over every recorded
+    kernel: replay the kernel's own fetch contract over every recorded
     decode-tick state and count the page DMAs it issues
-    (``fetched_page_counts`` shares ``kv_block_index`` with the
-    BlockSpec, so this tracks the kernel's real addressing, not a
+    (``fetched_page_counts`` shares ``page_fetched`` with the kernel's
+    DMA loop, so this tracks the kernel's real addressing, not a
     parallel model) — and ASSERT the live-token bound per slot per
     tick: fetched pages × page_size ≤ live tokens + one page of slack
-    (inactive rows cost exactly the one clamped slack page)."""
+    (inactive rows fetch nothing)."""
     from repro.kernels.paged_attention import fetched_page_counts
     per_tok = autotune.paged_kv_bytes_per_token(cfg.n_kv_heads,
                                                 cfg.head_dim_)
@@ -101,7 +101,7 @@ def measured_kernel_read_kb_per_tok(cfg, tick_states) -> float:
         counts = fetched_page_counts(bt, lens, PAGE)
         for slot, (fetched, live) in enumerate(zip(counts, lens)):
             assert fetched * PAGE <= live + PAGE, (
-                f"kernel index map fetched {fetched} pages for a slot "
+                f"kernel fetch contract fetched {fetched} pages for a slot "
                 f"with {live} live tokens (tables row "
                 f"{bt[slot].tolist()}) — reads must scale with live "
                 f"context, not table capacity")
@@ -136,7 +136,7 @@ def bench_one(cfg, params, n_requests: int, *, paged: bool,
         paged and paged_kernel
         and ops.paged_attention_blocks(
             PAGE, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads,
-            cfg.head_dim_) is not None)
+            cfg.head_dim_, pages_for_tokens(MAX_SEQ, PAGE)) is not None)
     tick_states = []
     if kernel_active:
         # capture each decode tick's scalar-prefetch operands so the

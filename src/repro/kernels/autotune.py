@@ -245,22 +245,25 @@ def _choose_blocks_cached(m: int, k_s: int, k_b: int, n: int,
 
 
 # ---------------------------------------------------------------------------
-# Paged-attention decode kernel (KV page tiles)
+# Paged-attention decode kernel (KV compute blocks)
 # ---------------------------------------------------------------------------
-# The paged flash-decode kernel's KV tile is one pool page per grid step:
-# (ps, bh, dh) slabs of K and V for `bh` kv heads at a time.  The only
-# free block dim is `bh` — pages are non-contiguous in the pool, so the
-# tile cannot span pages, and ps/dh are fixed by the pool layout.  The
-# model picks the largest `bh` whose double-buffered K/V tiles + the q
-# tile + the f32 (m, l, acc) scratch fit the VMEM budget (fewer grid
-# steps, better DMA overlap), and exposes the per-token KV read bytes
-# the serving bench asserts against.
+# The paged flash-decode kernel gathers `ppcb` pool pages per grid step
+# into a (ppcb*ps*hkv, dh) VMEM block of K and one of V (every kv head;
+# see kernels/paged_attention.py), double-buffered across steps.  The one
+# free dim is `ppcb` (pages per compute block); ps/hkv/dh are fixed by
+# the pool layout.  A grid step costs a fixed overhead, so the model
+# takes the largest `ppcb` — at most PAGED_BLOCK_TOKENS tokens, at most
+# the table width — whose buffers, q/out tiles, scratch and temporaries
+# fit the VMEM budget.  It also exposes the per-token KV read bytes the
+# serving bench asserts against.
+
+PAGED_BLOCK_TOKENS = 512   # tokens per compute block, at most
 
 
 @dataclass(frozen=True)
 class PagedAttnChoice:
-    """KV-tile pick for one paged-attention call plus its cost terms."""
-    bh: int                    # kv heads per block
+    """KV-block pick for one paged-attention call plus its cost terms."""
+    ppcb: int                  # pool pages per compute block
     vmem_bytes: int
     kv_bytes_per_token: int    # K+V bytes one live token costs per read
 
@@ -279,37 +282,45 @@ def paged_read_bytes(context_len: int, ps: int, hkv: int, dh: int,
     return pages * ps * paged_kv_bytes_per_token(hkv, dh, itemsize)
 
 
-def paged_attn_vmem_bytes(bh: int, rep: int, dh: int, ps: int,
-                          kv_itemsize: int = 2, q_itemsize: int = 2) -> int:
-    """Per-step VMEM footprint: double-buffered K/V page tiles and q
-    tile, the f32 output tile, and the resident (m, l, acc) scratch."""
-    kv = 2 * ps * bh * dh * kv_itemsize          # one K + one V tile
-    qo = bh * rep * dh * (q_itemsize + 4)        # q tile + f32 out tile
-    scratch = bh * rep * (dh + 2) * 4            # acc + m + l
-    return 2 * (kv + qo) + scratch
+def paged_attn_vmem_bytes(hkv: int, rep: int, dh: int, ps: int,
+                          ppcb: int = 1, kv_itemsize: int = 2,
+                          q_itemsize: int = 2) -> int:
+    """Per-step VMEM footprint: the K and V block buffers (two slots
+    each), the double-buffered q tile and f32 output tile, the resident
+    (m, l, acc) scratch and row mask, and the block's temporaries (its K
+    and V rows loaded, f32 scores, probabilities and mask over every
+    query head x every row)."""
+    rows = ppcb * ps * hkv
+    hq = hkv * rep
+    kv = 2 * 2 * rows * dh * kv_itemsize
+    qo = 2 * hq * dh * (q_itemsize + 4)
+    scratch = hq * (dh + 2) * 4 + rows * 4
+    temps = 2 * rows * dh * kv_itemsize + 3 * hq * rows * 4
+    return kv + qo + scratch + temps
 
 
-def choose_paged_blocks(hkv: int, rep: int, dh: int, ps: int,
+def choose_paged_blocks(hkv: int, rep: int, dh: int, ps: int, nblk: int,
                         vmem_budget: Optional[int] = None,
                         ) -> Optional[PagedAttnChoice]:
-    """Pick the kv-heads-per-block tile for a paged-attention shape, or
-    None when even bh=1 cannot fit (callers fall back to the XLA gather
-    path).  Memoized like :func:`choose_blocks` — decode hits the same
-    (hkv, rep, dh, ps) key every layer of every tick."""
+    """Pick the pages per compute block of a paged-attention shape
+    (``nblk`` = block-table width), or None when even one page cannot
+    fit (callers fall back to the XLA gather path).  Memoized like
+    :func:`choose_blocks` — decode hits the same key every layer of
+    every tick."""
     return _choose_paged_cached(
-        hkv, rep, dh, ps,
+        hkv, rep, dh, ps, nblk,
         VMEM_BUDGET if vmem_budget is None else vmem_budget)
 
 
 @functools.lru_cache(maxsize=1024)
-def _choose_paged_cached(hkv: int, rep: int, dh: int, ps: int,
+def _choose_paged_cached(hkv: int, rep: int, dh: int, ps: int, nblk: int,
                          vmem_budget: int) -> Optional[PagedAttnChoice]:
-    if hkv <= 0 or rep <= 0 or dh <= 0 or ps <= 0:
+    if hkv <= 0 or rep <= 0 or dh <= 0 or ps <= 0 or nblk <= 0:
         return None
-    for bh in _divisors(hkv, hkv):
-        vmem = paged_attn_vmem_bytes(bh, rep, dh, ps)
+    for ppcb in range(min(nblk, max(PAGED_BLOCK_TOKENS // ps, 1)), 0, -1):
+        vmem = paged_attn_vmem_bytes(hkv, rep, dh, ps, ppcb)
         if vmem <= vmem_budget:
-            return PagedAttnChoice(bh, vmem,
+            return PagedAttnChoice(ppcb, vmem,
                                    paged_kv_bytes_per_token(hkv, dh))
     return None
 

@@ -107,11 +107,12 @@ def padded_head_dim(dh: int) -> int:
     return ((dh + LANE - 1) // LANE) * LANE
 
 
-def paged_attention_blocks(ps: int, hkv: int, rep: int, dh: int,
+def paged_attention_blocks(ps: int, hkv: int, rep: int, dh: int, nblk: int,
                            pool_dh: int = None):
-    """Feasibility gate for the paged flash-decode kernel: the
-    autotuned KV-tile choice, or None when the kernel cannot serve the
-    shape and the caller must keep the XLA-gather reference path.  On a
+    """Feasibility gate for the paged flash-decode kernel (``nblk`` =
+    block-table width): the autotuned pages-per-block choice, or None
+    when the kernel cannot serve the shape and the caller must keep the
+    XLA-gather reference path.  On a
     real TPU backend the pool layout must respect the MXU/VPU tiling
     floors — ``dh`` misalignment is absorbed by the pool's padded head
     dim (:func:`padded_head_dim`; ``pool_dh`` is the pool's actual last
@@ -123,17 +124,17 @@ def paged_attention_blocks(ps: int, hkv: int, rep: int, dh: int,
     if not INTERPRET and (pool_dh % LANE != 0
                           or ps % autotune.SUBLANE != 0):
         return None
-    return autotune.choose_paged_blocks(hkv, rep, pool_dh, ps)
+    return autotune.choose_paged_blocks(hkv, rep, pool_dh, ps, nblk)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, context_lens: jax.Array, *,
-                    window=None, softcap=None, bh=None) -> jax.Array:
+                    window=None, softcap=None, ppcb=None) -> jax.Array:
     """Paged flash-decode forward (see kernels.paged_attention); the
     caller is expected to have consulted :func:`paged_attention_blocks`
     first — this wrapper only pins the interpret mode."""
     return _paged_attn(q, k_pool, v_pool, block_tables, context_lens,
-                       window=window, softcap=softcap, bh=bh,
+                       window=window, softcap=softcap, ppcb=ppcb,
                        interpret=INTERPRET)
 
 

@@ -17,8 +17,8 @@ Mechanics (the scalar-prefetch contract):
   with ``j < nblk`` DMAs context page ``bt_read[j]`` HBM→VMEM through the
   K/V BlockSpec index map.  Steps past the live context (``j*ps >=
   start``), before the sliding-window start, or on unassigned entries
-  clamp onto an already-fetched page — no new DMA, mirroring
-  ``paged_attention.kv_block_index``.
+  clamp onto an already-fetched page — no new DMA, the index-map form
+  of the decode kernel's ``paged_attention.page_fetched``.
 * ``bt_write`` is the request's *writable* row
   (:meth:`repro.runtime.paged_cache.BlockTables.writable_row`): shared
   (prefix-attached / COW) blocks are masked to ``-1`` and their writes
@@ -66,7 +66,7 @@ NEG_INF = -1e30
 def ctx_block_index(j, bt_read, start, *, ps: int, nblk: int,
                     window: Optional[int]):
     """Context pool page the K/V BlockSpec addresses at grid step
-    ``(·, j)`` — the prefill twin of ``paged_attention.kv_block_index``:
+    ``(·, j)`` — the prefill twin of ``paged_attention.page_fetched``:
     steps past the last context page (``j*ps >= start``), before the
     sliding-window start, or on dead entries clamp onto an
     already-fetched page so the pipeline issues no new DMA."""

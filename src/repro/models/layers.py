@@ -456,13 +456,13 @@ def attention_decode_paged(cfg: ArchConfig, par: Parallel, p: Tree,
     from repro.kernels import ops
     hkv = k.shape[2]
     hq = q.shape[2]
-    choice = (ops.paged_attention_blocks(ps, hkv, hq // hkv, dh,
+    choice = (ops.paged_attention_blocks(ps, hkv, hq // hkv, dh, nblk,
                                          pool_dh=dh_pool)
               if use_kernel and lengths is not None else None)
     if choice is not None:
         o = ops.paged_attention(q[:, 0], ck[layer], cv[layer],
                                 block_tables, lengths, window=window,
-                                softcap=cfg.logit_softcap, bh=choice.bh)
+                                softcap=cfg.logit_softcap, ppcb=choice.ppcb)
         o = o[:, None]                                   # (B, 1, hq, dh)
     else:
         bt = jnp.clip(block_tables, 0)                   # (B, nblk)

@@ -78,6 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels.paged_attention import attn_block_counts
 from repro.models import model as M
 from repro.models import transformer as T
 from repro.models.common import Parallel
@@ -200,10 +201,29 @@ class _PagedBackend:
             use_kernel=use_kernel))
         self.prefill_chunk_calls = 0
         self.prefill_kv_read_bytes = 0
+        # (ppcb, nblk, window) of the decode kernel's calls, for the
+        # live compute-block counter; None where decode takes the XLA path
+        self._attn_blocks = (self._kernel_blocks(max_blocks)
+                             if use_kernel else None)
 
     @property
     def page_size(self) -> int:
         return self.pool.page_size
+
+    def _kernel_blocks(self, nblk: int):
+        from repro.kernels import ops
+        cfg = self.eng.cfg
+        kinds = {k for s in cfg.stages for k in s.pattern} & set(T.ATTN_KINDS)
+        if not kinds:
+            return None
+        hkv = self.eng.par.kv_heads_run(cfg.n_kv_heads, cfg.n_heads)
+        choice = ops.paged_attention_blocks(
+            self.page_size, hkv, cfg.n_heads // hkv, cfg.head_dim_, nblk)
+        if choice is None:
+            return None
+        # layers of one config share their window; a mix counts none
+        windows = {T._kind_window(cfg, k, self.eng.max_seq) for k in kinds}
+        return choice.ppcb, nblk, windows.pop() if len(windows) == 1 else None
 
     def free_pages(self) -> Optional[int]:
         """Admission headroom: the free list plus whatever the prefix
@@ -299,6 +319,10 @@ class _PagedBackend:
         if active is not None:
             bt = np.where(active[:, None], bt, -1)
             lens = np.where(active, lens, 0)
+        if self._attn_blocks is not None:
+            ppcb, nblk, window = self._attn_blocks
+            self.eng.metrics.on_attn_blocks(*attn_block_counts(
+                lens, self.page_size, ppcb, nblk, window))
         logits, self.caches = self._decode(params, toks, pos, self.caches,
                                            jnp.asarray(bt),
                                            jnp.asarray(lens))

@@ -68,6 +68,10 @@ class EngineMetrics:
         self.prefill_chunks = 0
         self.prefill_chunk_tokens = 0
         self.prefill_tokens_skipped = 0
+        # paged decode kernel: compute blocks that held live tokens, and
+        # all blocks of its grids, summed over decode ticks
+        self.attn_blocks_live = 0
+        self.attn_blocks = 0
         # host runtime inside the engine's ticks: XLA compiles, and
         # garbage collections and their pauses by generation
         self.compiles = 0
@@ -158,6 +162,12 @@ class EngineMetrics:
         skipped the prefill compute entirely."""
         self.prefill_tokens_skipped += n_tokens
 
+    def on_attn_blocks(self, live: int, total: int) -> None:
+        """One decode step's paged-attention grid had ``total`` compute
+        blocks per layer, ``live`` of them over live tokens."""
+        self.attn_blocks_live += live
+        self.attn_blocks += total
+
     def on_phase_time(self, phase: str, seconds: float) -> None:
         """Record one jitted step's wall time for ``phase``.  Decode runs
         at M=n_slots while prefill runs at the bucket length, so the two
@@ -229,6 +239,8 @@ class EngineMetrics:
             "prefill_chunks": self.prefill_chunks,
             "prefill_chunk_tokens": self.prefill_chunk_tokens,
             "prefill_tokens_skipped": self.prefill_tokens_skipped,
+            "attn_blocks_live": self.attn_blocks_live,
+            "attn_blocks": self.attn_blocks,
             "preemptions": self.preemptions,
             "expirations": self.expirations,
             "cancellations": self.cancellations,

@@ -56,11 +56,24 @@ def _pool_state(rng, num_pages, ps, hkv, dh, dtype):
     return k_pool, v_pool
 
 
+# pages per compute block: one page, a divisor, non-divisors of the
+# 5-page table, and the whole table (None)
+PPCB = [1, 2, 3, 4, None]
+
+
+def _run(q, k_pool, v_pool, bt, lens, ppcb, **kw):
+    nblk = np.asarray(bt).shape[1]
+    return np.asarray(ops.paged_attention(
+        q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens),
+        ppcb=nblk if ppcb is None else ppcb, **kw))
+
+
+@pytest.mark.parametrize("ppcb", PPCB)
 @pytest.mark.parametrize("rep", [1, 2, 4])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_kernel_parity_ragged_gqa(rng, rep, dtype):
+def test_kernel_parity_ragged_gqa(rng, rep, dtype, ppcb):
     """Ragged lengths (incl. a page-boundary length and an inactive
-    len=0 row) across GQA head ratios."""
+    len=0 row) across GQA head ratios and pages per compute block."""
     b, num_pages, ps, hkv, dh, nblk = 4, 20, 8, 2, 16, 5
     hq = hkv * rep
     q = jnp.asarray(rng.normal(size=(b, hq, dh)), dtype)
@@ -70,9 +83,7 @@ def test_kernel_parity_ragged_gqa(rng, rep, dtype):
     bt[1, :1] = [0]
     bt[2, :5] = [2, 4, 5, 9, 11]
     lens = np.asarray([17, 8, 40, 0], np.int32)     # row 3: inactive
-    out = np.asarray(ops.paged_attention(q, k_pool, v_pool,
-                                         jnp.asarray(bt),
-                                         jnp.asarray(lens)))
+    out = _run(q, k_pool, v_pool, bt, lens, ppcb)
     ref = _oracle(q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens))
     tol = 1e-5 if dtype == jnp.float32 else 0.06 * math.sqrt(dh)
     np.testing.assert_allclose(out[:3], ref[:3], rtol=2e-2, atol=tol)
@@ -80,25 +91,45 @@ def test_kernel_parity_ragged_gqa(rng, rep, dtype):
     np.testing.assert_array_equal(out[3], 0.0)
 
 
-def test_kernel_parity_freed_pages_mid_table(rng):
+@pytest.mark.parametrize("ppcb", PPCB)
+def test_kernel_parity_block_edges(rng, ppcb):
+    """Lengths that end exactly on a compute-block edge and one token
+    past it: the last block's masked tail and the next block's single
+    live token."""
+    b, num_pages, ps, hkv, dh, nblk = 4, 24, 8, 2, 16, 5
+    tile = (nblk if ppcb is None else ppcb) * ps
+    q = jnp.asarray(rng.normal(size=(b, hkv * 2, dh)), jnp.float32)
+    k_pool, v_pool = _pool_state(rng, num_pages, ps, hkv, dh, jnp.float32)
+    bt = np.arange(b * nblk, dtype=np.int32).reshape(b, nblk)
+    lens = np.asarray([tile, min(tile + 1, nblk * ps), tile - 1,
+                       nblk * ps], np.int32)
+    out = _run(q, k_pool, v_pool, bt, lens, ppcb)
+    ref = _oracle(q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens))
+    np.testing.assert_allclose(out, ref, rtol=2e-2, atol=1e-5)
+
+
+@pytest.mark.parametrize("ppcb", PPCB)
+def test_kernel_parity_freed_pages_mid_table(rng, ppcb):
     """-1 entries in the MIDDLE of a table (freed pages) are masked like
-    the implied-position reference, not attended via a clamped fetch."""
+    the implied-position reference, not attended via a clamped fetch —
+    also when the hole sits inside a live compute block."""
     b, num_pages, ps, hkv, dh, nblk = 2, 16, 8, 2, 16, 4
     q = jnp.asarray(rng.normal(size=(b, hkv * 2, dh)), jnp.float32)
     k_pool, v_pool = _pool_state(rng, num_pages, ps, hkv, dh, jnp.float32)
     bt = np.asarray([[5, -1, 8, 2],
                      [1, 3, -1, -1]], np.int32)
     lens = np.asarray([29, 14], np.int32)
-    out = np.asarray(ops.paged_attention(q, k_pool, v_pool,
-                                         jnp.asarray(bt),
-                                         jnp.asarray(lens)))
+    out = _run(q, k_pool, v_pool, bt, lens, ppcb)
     ref = _oracle(q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens))
     np.testing.assert_allclose(out, ref, rtol=2e-2, atol=1e-5)
 
 
+@pytest.mark.parametrize("ppcb", PPCB)
 @pytest.mark.parametrize("window,softcap", [(12, None), (12, 30.0),
                                             (3, None), (None, 30.0)])
-def test_kernel_parity_window_softcap(rng, window, softcap):
+def test_kernel_parity_window_softcap(rng, window, softcap, ppcb):
+    """Sliding windows that start mid-page and mid-block, with and
+    without a logit softcap."""
     b, num_pages, ps, hkv, dh, nblk = 3, 16, 8, 2, 16, 5
     q = jnp.asarray(rng.normal(size=(b, hkv * 2, dh)), jnp.float32)
     k_pool, v_pool = _pool_state(rng, num_pages, ps, hkv, dh, jnp.float32)
@@ -107,48 +138,75 @@ def test_kernel_parity_window_softcap(rng, window, softcap):
     bt[1, :2] = [0, 6]
     bt[2, :5] = [2, 4, 5, 9, 11]
     lens = np.asarray([23, 9, 37], np.int32)
-    out = np.asarray(ops.paged_attention(
-        q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens),
-        window=window, softcap=softcap))
+    out = _run(q, k_pool, v_pool, bt, lens, ppcb, window=window,
+               softcap=softcap)
     ref = _oracle(q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens),
                   window=window, softcap=softcap)
     np.testing.assert_allclose(out, ref, rtol=2e-2, atol=1e-5)
 
 
+@pytest.mark.parametrize("ppcb", PPCB)
+def test_kernel_nan_in_unfetched_pages(rng, ppcb):
+    """Pool pages no row fetches hold NaN, and so do the -1 holes'
+    fallback page 0 and the tails past each row's table: the output
+    stays finite and matches the reference on a clean pool — a page
+    that is never fetched never reaches p @ V (0 x NaN is NaN)."""
+    b, num_pages, ps, hkv, dh, nblk = 3, 16, 8, 2, 16, 5
+    q = jnp.asarray(rng.normal(size=(b, hkv * 2, dh)), jnp.float32)
+    k_pool, v_pool = _pool_state(rng, num_pages, ps, hkv, dh, jnp.float32)
+    bt = np.asarray([[3, -1, 7, -1, -1],
+                     [4, 6, 8, 9, 10],
+                     [-1, -1, -1, -1, -1]], np.int32)
+    lens = np.asarray([20, 33, 0], np.int32)
+    used = np.zeros((num_pages,), bool)
+    used[[3, 7, 4, 6, 8, 9, 10]] = True
+    nan = jnp.asarray(~used)[:, None, None, None]
+    k_nan = jnp.where(nan, jnp.nan, k_pool)
+    v_nan = jnp.where(nan, jnp.nan, v_pool)
+    out = _run(q, k_nan, v_nan, bt, lens, ppcb, window=30)
+    assert np.isfinite(out).all()
+    ref = _oracle(q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens),
+                  window=30)
+    np.testing.assert_allclose(out[:2], ref[:2], rtol=2e-2, atol=1e-5)
+    np.testing.assert_array_equal(out[2], 0.0)
+
+
 def test_kernel_bh_sweep_block_size_independent(rng):
-    """Results must not depend on the kv-heads-per-block tile."""
+    """Results must not depend on the pages per compute block, with four
+    kv heads interleaved in each block's rows."""
     b, num_pages, ps, hkv, dh, nblk = 2, 12, 8, 4, 16, 3
     q = jnp.asarray(rng.normal(size=(b, hkv * 2, dh)), jnp.float32)
     k_pool, v_pool = _pool_state(rng, num_pages, ps, hkv, dh, jnp.float32)
     bt = np.asarray([[0, 1, 2], [3, 4, -1]], np.int32)
     lens = np.asarray([20, 11], np.int32)
     outs = [np.asarray(ops.paged_attention(
-        q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens), bh=bh))
-        for bh in (1, 2, 4)]
+        q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens), ppcb=ppcb))
+        for ppcb in (1, 2, 3)]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], rtol=1e-6, atol=1e-6)
 
 
 def test_fetched_page_counts_match_live_pages():
-    """The index-map replay (shared kv_block_index — what serving_bench
-    asserts on) issues exactly the live pages: ceil(len/ps) for active
-    rows, the single clamped slack page for inactive ones, and only the
+    """The fetch-contract replay (shared page_fetched — what
+    serving_bench asserts on) fetches exactly the live pages: ceil(len/ps)
+    for active rows, none for inactive ones and holes, and only the
     in-window pages under a sliding window."""
     from repro.kernels.paged_attention import fetched_page_counts
     ps = 8
     bt = np.asarray([[3, 7, 1, -1],      # 17 live tokens -> 3 pages
                      [0, -1, -1, -1],    # 8 live -> 1 page
                      [2, 4, 5, 9],       # 32 live -> 4 pages
-                     [-1, -1, -1, -1]],  # inactive -> 1 clamped page
+                     [-1, -1, -1, -1],   # inactive -> nothing
+                     [6, -1, 8, -1]],    # 20 live, one hole -> 2 pages
                     np.int32)
-    lens = np.asarray([17, 8, 32, 0], np.int32)
+    lens = np.asarray([17, 8, 32, 0, 20], np.int32)
     np.testing.assert_array_equal(
-        fetched_page_counts(bt, lens, ps), [3, 1, 4, 1])
-    # sliding window 8 over 32 live tokens: pages below the window
-    # start clamp onto the first in-window page -> 2 fetches at most
-    # (window spans positions 24..31 = page 3, plus the clamp target)
+        fetched_page_counts(bt, lens, ps), [3, 1, 4, 0, 2])
+    # sliding window 8 over 32 live tokens: positions 24..31 = page 3
+    # only; window 9 reaches one token into page 2
     win = fetched_page_counts(bt, lens, ps, window=8)
-    assert win[2] <= 2
+    assert win[2] == 1
+    assert fetched_page_counts(bt, lens, ps, window=9)[2] == 2
     # every row obeys the serving_bench gate: pages*ps <= live + ps
     for fetched, live in zip(fetched_page_counts(bt, lens, ps), lens):
         assert fetched * ps <= live + ps
@@ -158,17 +216,71 @@ def test_fetched_page_counts_match_live_pages():
 # Autotuned KV tiles
 # ---------------------------------------------------------------------------
 def test_choose_paged_blocks():
-    c = autotune.choose_paged_blocks(8, 4, 128, 16)
-    assert c is not None and 8 % c.bh == 0
+    c = autotune.choose_paged_blocks(8, 4, 128, 16, 160)
+    assert c is not None
     assert c.vmem_bytes <= autotune.VMEM_BUDGET
     assert c.kv_bytes_per_token == 2 * 8 * 128 * 2
-    # plenty of VMEM at serving shapes: all kv heads in one block
-    assert c.bh == 8
-    # a starved budget still degrades to bh=1 before giving up
-    tight = autotune.choose_paged_blocks(8, 4, 128, 16,
+    # a starved budget degrades to fewer pages before giving up
+    tight = autotune.choose_paged_blocks(8, 4, 128, 16, 160,
                                          vmem_budget=1 << 16)
-    assert tight is None or tight.bh <= c.bh
-    assert autotune.choose_paged_blocks(0, 4, 128, 16) is None
+    assert tight is None or tight.ppcb < c.ppcb
+    assert autotune.choose_paged_blocks(8, 4, 128, 16, 160,
+                                        vmem_budget=1 << 10) is None
+    assert autotune.choose_paged_blocks(0, 4, 128, 16, 160) is None
+
+
+def test_choose_paged_blocks_pages_per_block():
+    """ppcb comes from the shape: 512 tokens a block where the table and
+    VMEM allow, the whole table when it is narrower, fewer pages under a
+    tight budget, and a footprint that grows with ppcb."""
+    # qwen2.5-3b serving cell: 2 kv heads of 128, page 16, 160-page table
+    assert autotune.choose_paged_blocks(2, 8, 128, 16, 160).ppcb == 32
+    # qwen3-4b: 8 kv heads, 4 query heads each
+    assert autotune.choose_paged_blocks(8, 4, 128, 16, 288).ppcb == 32
+    # chip_smoke's 32-page table, and a narrower one
+    assert autotune.choose_paged_blocks(2, 8, 128, 16, 32).ppcb == 32
+    assert autotune.choose_paged_blocks(2, 8, 128, 16, 5).ppcb == 5
+    budget = autotune.paged_attn_vmem_bytes(2, 8, 128, 16, 8)
+    tight = autotune.choose_paged_blocks(2, 8, 128, 16, 160,
+                                         vmem_budget=budget)
+    assert tight.ppcb == 8 and tight.vmem_bytes == budget
+    sizes = [autotune.paged_attn_vmem_bytes(2, 8, 128, 16, p)
+             for p in (1, 2, 4, 8, 16, 32)]
+    assert sizes == sorted(sizes) and len(set(sizes)) == len(sizes)
+    # the cell's double-buffered K and V blocks of 512 tokens: 1 MiB
+    assert sizes[-1] >= 4 * 512 * 2 * 128 * 2
+
+
+def test_attn_block_counts_with_window():
+    """The engine's live-block counter on a hand-made batch: blocks up
+    to each row's length, less those wholly below the window start."""
+    from repro.kernels.paged_attention import attn_block_counts
+    ps, ppcb, nblk = 8, 2, 7                   # 16-token blocks, 4 a row
+    lens = np.asarray([0, 1, 16, 17, 56, 40])
+    assert attn_block_counts(lens, ps, ppcb, nblk) == (0 + 1 + 1 + 2 + 4
+                                                       + 3, 6 * 4)
+    # window 20: row 56 sees 36..55 -> blocks 2, 3; row 40 sees
+    # 20..39 -> blocks 1, 2; row 17 sees 0..16 -> blocks 0, 1
+    assert attn_block_counts(lens, ps, ppcb, nblk, window=20) == (
+        0 + 1 + 1 + 2 + 2 + 2, 24)
+
+
+def test_engine_counts_attention_blocks(subject):
+    """EngineMetrics carries the running live / all compute-block sums
+    of the decode kernel, and the live ones are a share of all."""
+    cfg, params = subject
+    eng = Engine(cfg, PAR, params, n_slots=2, max_seq=64,
+                 prefill_buckets=(16, 32), paged=True, page_size=8)
+    local = np.random.default_rng(0)
+    for n in (9, 20):
+        eng.submit(local.integers(1, cfg.vocab, size=n).astype(np.int32),
+                   max_new=4)
+    eng.run()
+    snap = eng.metrics.snapshot()
+    ppcb = eng.backend._attn_blocks[0]
+    nb = -(-8 // ppcb)
+    assert snap["attn_blocks"] == 2 * nb * snap["ticks"]
+    assert 0 < snap["attn_blocks_live"] <= snap["attn_blocks"]
 
 
 def test_paged_read_bytes_page_slack():
@@ -381,11 +493,12 @@ def test_padded_head_dim_policy_and_gate(monkeypatch):
     assert ops.padded_head_dim(96) == 128
     assert ops.padded_head_dim(200) == 256
     # dh=96 alone fails the lane floor; with its padded pool it passes
-    assert ops.paged_attention_blocks(8, 2, 2, 96, pool_dh=96) is None
-    assert ops.paged_attention_blocks(8, 2, 2, 96, pool_dh=128) is not None
+    assert ops.paged_attention_blocks(8, 2, 2, 96, 4, pool_dh=96) is None
+    assert ops.paged_attention_blocks(8, 2, 2, 96, 4,
+                                      pool_dh=128) is not None
     # a pool narrower than the query head dim is never feasible
     monkeypatch.setattr(ops, "INTERPRET", True)
-    assert ops.paged_attention_blocks(8, 2, 2, 96, pool_dh=64) is None
+    assert ops.paged_attention_blocks(8, 2, 2, 96, 4, pool_dh=64) is None
 
 
 @pytest.mark.parametrize("use_kernel", [True, False])

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import registry
 from repro.core.qlinear import QuantConfig
 from repro.core.saliency import round_salient
+from repro.kernels import autotune
 from repro.kernels.mixed_matmul import mixed_matmul
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.paged_prefill import paged_prefill
@@ -90,6 +91,30 @@ def test_paged_attention_compiles(one_chip):
               ((DECODE_M, NBLK), jnp.int32), ((DECODE_M,), jnp.int32)]
     found = _compiled_kernels(
         lambda *a: paged_attention(*a, interpret=False), shapes, one_chip)
+    assert found == {"paged_attention": 1}
+
+
+# the paged decode kernel at serving shapes, with the autotuner's ppcb:
+# (config, decode rows, table width, pool pages)
+SERVING_DECODE = {
+    "qwen2.5-3b.batch_decode": ("qwen2.5-3b", 64, 160, 2432),
+    "qwen3-4b": ("qwen3-4b", 8, 288, 2304),
+}
+
+
+@pytest.mark.parametrize("cell", list(SERVING_DECODE))
+def test_paged_attention_compiles_serving(one_chip, cell):
+    arch, b, nblk, pages = SERVING_DECODE[cell]
+    cfg = registry.get(arch)
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    choice = autotune.choose_paged_blocks(hkv, hq // hkv, dh, PAGE, nblk)
+    assert choice is not None
+    pool = ((pages, PAGE, hkv, dh), jnp.bfloat16)
+    shapes = [((b, hq, dh), jnp.bfloat16), pool, pool,
+              ((b, nblk), jnp.int32), ((b,), jnp.int32)]
+    found = _compiled_kernels(
+        lambda *a: paged_attention(*a, ppcb=choice.ppcb, interpret=False),
+        shapes, one_chip)
     assert found == {"paged_attention": 1}
 
 
