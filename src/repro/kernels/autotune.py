@@ -39,9 +39,13 @@ from typing import Optional, Tuple
 
 from repro.launch.hlo_analysis import HBM_BW, PEAK_FLOPS
 
-# Mosaic's default scoped-VMEM limit on v5e is 16 MiB; the footprint
-# models below count pipeline buffers and kernel temporaries, and keep a
-# quarter of the limit as margin for what they miss.
+# Mosaic's default scoped-VMEM limit on v5e.  The matmul and decode
+# footprint models below count pipeline buffers and kernel temporaries
+# and keep a quarter of the limit as margin for what they miss; the
+# chunked-prefill model is bounded from above by Mosaic's own count
+# (compiled at page 16, head dims 32 to 512) and is held to the limit
+# itself.
+VMEM_LIMIT = 16 * 1024 * 1024
 VMEM_BUDGET = 12 * 1024 * 1024
 LANE = 128            # TPU lane width: last-dim floor of a Mosaic block
 SUBLANE = 8           # second-to-last-dim floor of a Mosaic block
@@ -330,24 +334,42 @@ def _choose_paged_cached(hkv: int, rep: int, dh: int, ps: int, nblk: int,
 # ---------------------------------------------------------------------------
 # The chunked-prefill kernel keeps the whole chunk's queries and the
 # online-softmax scratch resident while streaming one KV page per grid
-# step, so its VMEM footprint scales with (bh, rep, C) instead of the
-# decode kernel's (bh, rep).  `bh` is again the only free dim; the model
-# picks the largest fitting one and exposes the per-chunk traffic terms
+# step, so its VMEM footprint scales with the query rows (bh, rep, C)
+# instead of the decode kernel's (hkv, rep).  `bh` (kv heads per grid
+# step) is the only free dim; the model picks the largest one Mosaic can
+# lower whose footprint fits, and exposes the per-chunk traffic terms
 # the serving bench accounts against (mirroring paged_read_bytes).
 
 
 def paged_prefill_vmem_bytes(bh: int, rep: int, dh: int, ps: int, c: int,
                              kv_itemsize: int = 2,
                              q_itemsize: int = 2) -> int:
-    """Per-step VMEM footprint of the chunked-prefill kernel:
-    double-buffered context K/V page tiles AND chunk K/V tiles, the
-    resident q block, the f32 output tile, and the (m, l, acc)
-    scratch."""
-    kv = 4 * ps * bh * dh * kv_itemsize          # ctx K/V + chunk K/V tiles
-    q = bh * rep * c * dh * q_itemsize
-    out = bh * rep * c * dh * 4
-    scratch = bh * rep * c * (dh + 2) * 4        # acc + m + l
-    return 2 * kv + q + out + scratch
+    """Per-step VMEM footprint of the chunked-prefill kernel as Mosaic
+    allocates it: every pipelined block twice (the q block, the f32
+    output block, the context and chunk K/V page tiles in, the K/V page
+    tiles out), the (m, l, acc) scratch with the (bh, rep, C, 1) running
+    max and denominator padded to 128 lanes, one KV page's f32 scores
+    and probabilities over every query row, lane-padded too, and one
+    page's K in f32 with a lane tile per row of dh.  A block's last dim
+    (dh) is padded to 128 lanes and a K/V page tile's second-minor dim
+    (bh) to 8 sublanes.
+
+    Bounded from above by Mosaic's v5e scoped-VMEM count, found by
+    compiling the kernel under shrinking limits at page 16 (the test
+    ``test_paged_prefill_vmem_model_bounds_mosaic`` holds each shape's
+    compile to this count): at dh 128 and 64 to 8192 query rows
+    (bh*rep*C) the count here is 11-60% over Mosaic's, 11-18% at 4096
+    rows and more (Qwen2.5-3B's bh=2, rep=8, C=256: 15.4 MiB against
+    13.6); at dh 256, 384 and 512, and 32 and 64 (lane-padded), 7-103%
+    over."""
+    rows = bh * rep * c
+    lanes = -(-ps // LANE) * LANE
+    dhp = -(-dh // LANE) * LANE
+    page = ps * (-(-bh // SUBLANE) * SUBLANE) * dhp * kv_itemsize
+    blocks = rows * dhp * (q_itemsize + 4) + 6 * page
+    scratch = rows * (dhp + 2 * LANE) * 4
+    temps = rows * lanes * (4 + kv_itemsize) + dhp * LANE * 4
+    return 2 * blocks + scratch + temps
 
 
 def paged_prefill_read_bytes(start: int, length: int, ps: int, hkv: int,
@@ -374,12 +396,15 @@ def choose_prefill_blocks(c: int, hkv: int, rep: int, dh: int, ps: int,
                           vmem_budget: Optional[int] = None,
                           ) -> Optional[PagedPrefillChoice]:
     """Pick the kv-heads-per-block tile for a chunked-prefill shape, or
-    None when even bh=1 cannot fit (callers fall back to the XLA
-    dense-gather path).  Memoized like the other choosers — every chunk
-    of every prompt hits the same (C, hkv, rep, dh, ps) key."""
+    None when no tile Mosaic can lower fits (callers fall back to the
+    XLA dense-gather path).  ``bh`` is the second-minor dim of the K/V
+    page blocks, so Mosaic takes only all ``hkv`` heads or a multiple of
+    8: at 8 KV heads and a 256-token chunk nothing fits.  Memoized like
+    the other choosers — every chunk of every prompt hits the same
+    (C, hkv, rep, dh, ps) key."""
     return _choose_prefill_cached(
         c, hkv, rep, dh, ps,
-        VMEM_BUDGET if vmem_budget is None else vmem_budget)
+        VMEM_LIMIT if vmem_budget is None else vmem_budget)
 
 
 @functools.lru_cache(maxsize=1024)
@@ -388,6 +413,8 @@ def _choose_prefill_cached(c: int, hkv: int, rep: int, dh: int, ps: int,
     if c <= 0 or hkv <= 0 or rep <= 0 or dh <= 0 or ps <= 0 or c % ps:
         return None
     for bh in _divisors(hkv, hkv):
+        if bh != hkv and bh % SUBLANE:
+            continue
         vmem = paged_prefill_vmem_bytes(bh, rep, dh, ps, c)
         if vmem <= vmem_budget:
             return PagedPrefillChoice(bh, vmem,

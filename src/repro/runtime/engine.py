@@ -205,6 +205,11 @@ class _PagedBackend:
         # live compute-block counter; None where decode takes the XLA path
         self._attn_blocks = (self._kernel_blocks(max_blocks)
                              if use_kernel else None)
+        # the tiles the autotuner chose for the served shapes, once
+        m = eng.metrics
+        m.attn_ppcb = self._attn_blocks[0] if self._attn_blocks else None
+        m.prefill_bh = (self._prefill_bh()
+                        if use_kernel and eng.chunked_prefill else None)
 
     @property
     def page_size(self) -> int:
@@ -224,6 +229,17 @@ class _PagedBackend:
         # layers of one config share their window; a mix counts none
         windows = {T._kind_window(cfg, k, self.eng.max_seq) for k in kinds}
         return choice.ppcb, nblk, windows.pop() if len(windows) == 1 else None
+
+    def _prefill_bh(self) -> Optional[int]:
+        """kv heads per grid step of the chunk kernel, or None where the
+        chunk step takes the XLA path."""
+        from repro.kernels import ops
+        cfg = self.eng.cfg
+        hkv = self.eng.par.kv_heads_run(cfg.n_kv_heads, cfg.n_heads)
+        choice = ops.paged_prefill_blocks(
+            self.eng.prefill_chunk, self.page_size, hkv,
+            cfg.n_heads // hkv, cfg.head_dim_)
+        return None if choice is None else choice.bh
 
     def free_pages(self) -> Optional[int]:
         """Admission headroom: the free list plus whatever the prefix

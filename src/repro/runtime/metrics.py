@@ -72,6 +72,12 @@ class EngineMetrics:
         # all blocks of its grids, summed over decode ticks
         self.attn_blocks_live = 0
         self.attn_blocks = 0
+        # tiles the autotuner chose for the served shapes, set once by
+        # the paged backend (None: that step takes the XLA path):
+        # paged_attention's pages per compute block, paged_prefill's kv
+        # heads per grid step
+        self.attn_ppcb: Optional[int] = None
+        self.prefill_bh: Optional[int] = None
         # host runtime inside the engine's ticks: XLA compiles, and
         # garbage collections and their pauses by generation
         self.compiles = 0
@@ -241,6 +247,8 @@ class EngineMetrics:
             "prefill_tokens_skipped": self.prefill_tokens_skipped,
             "attn_blocks_live": self.attn_blocks_live,
             "attn_blocks": self.attn_blocks,
+            "attn_ppcb": self.attn_ppcb,
+            "prefill_bh": self.prefill_bh,
             "preemptions": self.preemptions,
             "expirations": self.expirations,
             "cancellations": self.cancellations,

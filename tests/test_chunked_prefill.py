@@ -113,6 +113,69 @@ def test_autotune_prefill_choice():
         (2 + 1) * 16 * autotune.paged_kv_bytes_per_token(2, 16)
 
 
+# (chunk, hkv, rep) -> the chooser's bh at dh 128, page 16: Qwen2.5-3B's
+# chunk keeps all its 2 kv heads a grid step; Qwen3-4B's 8 kv heads fit
+# a 128-token chunk only, since bh 4, 2 and 1 are not tiles Mosaic
+# lowers (a K/V page block's second-minor dim) and all 8 heads at 256
+# tokens need about 27.5 MiB of scoped VMEM; 16 kv heads split into 8s
+@pytest.mark.parametrize("c,hkv,rep,bh", [
+    (256, 2, 8, 2),              # qwen2.5-3b.batch_decode
+    (256, 8, 4, None),           # qwen3-4b at a 256-token chunk
+    (128, 8, 4, 8),              # qwen3-4b.long_decode
+    (64, 16, 4, 16),
+    (128, 16, 4, 8),
+    (512, 4, 8, None),
+])
+def test_prefill_chooser_picks_tiles_mosaic_lowers(c, hkv, rep, bh):
+    from repro.kernels import autotune
+    ch = autotune.choose_prefill_blocks(c, hkv, rep, 128, 16)
+    assert (ch and ch.bh) == bh
+    if ch is not None:
+        assert ch.vmem_bytes <= autotune.VMEM_LIMIT
+    # the lane-padded (m, l) scratch and double-buffered q/out blocks
+    # are counted: 8 heads x 4 x 256 rows need more than the limit
+    assert autotune.paged_prefill_vmem_bytes(8, 4, 128, 16, 256) > \
+        autotune.VMEM_LIMIT
+
+
+def test_prefill_vmem_count_pads_lanes_and_sublanes():
+    """Mosaic lays a block's last dim on 128 lanes and a K/V page tile's
+    kv heads on 8 sublanes: a head dim of 64 costs what 128 does, and one
+    kv head a grid step what 8 do at the same query rows."""
+    from repro.kernels import autotune
+    vmem = autotune.paged_prefill_vmem_bytes
+    assert vmem(8, 2, 64, 16, 128) == vmem(8, 2, 128, 16, 128)
+    assert vmem(1, 8, 128, 16, 64) == vmem(8, 1, 128, 16, 64)
+    assert vmem(16, 1, 128, 16, 64) > vmem(8, 2, 128, 16, 64)
+
+
+def test_untileable_chunk_takes_the_xla_fallback(monkeypatch):
+    """At 8 kv heads of 128 and a 256-token chunk no kernel tile fits:
+    the engine's chunk step takes the XLA dense-gather path (the kernel
+    is never called) and still serves, and records no prefill tile."""
+    import dataclasses
+
+    from repro.configs.base import Stage
+    cfg = dataclasses.replace(registry.get("qwen3-4b"), d_model=256,
+                              d_ff=256, vocab=512,
+                              stages=(Stage(("dense",), 1),))
+    assert ops.paged_prefill_blocks(256, 16, 8, 4, 128) is None
+
+    def refuse(*a, **kw):
+        raise AssertionError("paged_prefill kernel called")
+
+    monkeypatch.setattr(ops, "paged_prefill", refuse)
+    params = M.init_params(cfg, PAR, jax.random.PRNGKey(0))
+    eng = Engine(cfg, PAR, params, n_slots=2, max_seq=512, paged=True,
+                 page_size=16, chunked_prefill=True, prefill_chunk=256)
+    assert eng.metrics.prefill_bh is None
+    assert eng.metrics.attn_ppcb is not None
+    req = eng.submit(np.arange(1, 300, dtype=np.int32), max_new=2)
+    while not req.done:
+        eng.tick()
+    assert eng.metrics.prefill_chunks == 2 and len(req.out_tokens) == 2
+
+
 # ---------------------------------------------------------------------------
 # Model-level: chunked == whole-prompt prefill (f32 logits)
 # ---------------------------------------------------------------------------

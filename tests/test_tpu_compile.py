@@ -1,6 +1,8 @@
 """Ahead-of-time compiles of the serving path's Pallas kernels for a TPU
 v5e, at Qwen2.5-3B's published widths and the shapes ``chip_smoke.py``
-serves (decode M = 4 slots, prefill chunk M = 64, page 16).
+serves (decode M = 4 slots, prefill chunk M = 64, page 16), and of the
+kernels and whole decode and chunk steps at the benchmark cells' widths
+and shapes (Qwen2.5-3B and Qwen3-4B).
 
 No chip is needed: the TPU compiler installed with JAX compiles for a
 described ``v5e:2x2`` topology.  Each compile must hold its kernel as a
@@ -9,19 +11,29 @@ fails here instead of on the chip, and a kernel that quietly fell back
 to XLA fails too.  The topology is described inside the module fixture
 (never at import): only the worker that runs this file loads libtpu.
 """
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import registry
+from repro.configs.base import Stage
+from repro.core.pipeline import quantize_params_data_free
 from repro.core.qlinear import QuantConfig
 from repro.core.saliency import round_salient
-from repro.kernels import autotune
+from repro.kernels import autotune, ops
 from repro.kernels.mixed_matmul import mixed_matmul
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.paged_prefill import paged_prefill
 from repro.launch.hlo_analysis import tpu_kernels
+from repro.models import model as M
+from repro.models.common import Parallel
+from repro.models.param import abstractify
 
 CFG = registry.get("qwen2.5-3b")
 DECODE_M = 4                  # chip_smoke's decode slots
@@ -118,14 +130,128 @@ def test_paged_attention_compiles_serving(one_chip, cell):
     assert found == {"paged_attention": 1}
 
 
+def _prefill_shapes(cfg, chunk, nblk, pages, n_layers):
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    pool = ((n_layers, pages + 1, PAGE, hkv, dh), jnp.bfloat16)
+    return [((chunk, hq, dh), jnp.bfloat16), ((chunk, hkv, dh), jnp.bfloat16),
+            ((chunk, hkv, dh), jnp.bfloat16), pool, pool,
+            ((nblk,), jnp.int32), ((nblk,), jnp.int32),
+            ((), jnp.int32), ((), jnp.int32)]
+
+
 def test_paged_prefill_compiles(one_chip):
-    pool = ((CFG.n_layers, POOL_PAGES + 1, PAGE, HKV, DH), jnp.bfloat16)
-    shapes = [((CHUNK, HQ, DH), jnp.bfloat16),
-              ((CHUNK, HKV, DH), jnp.bfloat16),
-              ((CHUNK, HKV, DH), jnp.bfloat16), pool, pool,
-              ((NBLK,), jnp.int32), ((NBLK,), jnp.int32),
-              ((), jnp.int32), ((), jnp.int32)]
+    shapes = _prefill_shapes(CFG, CHUNK, NBLK, POOL_PAGES, CFG.n_layers)
     found = _compiled_kernels(
         lambda *a: paged_prefill(*a, layer=CFG.n_layers - 1,
                                  interpret=False), shapes, one_chip)
     assert found == {"paged_prefill": 1}
+
+
+# the benchmark cells' serving shapes: (config, slots, max_seq, pool
+# pages, prefill chunk), as bench/traffic/*.json and bench/configs/*.json
+# state them
+SERVING = {
+    "qwen2.5-3b.batch_decode": ("qwen2.5-3b", 64, 2560, 2432, 256),
+    "qwen3-4b.long_decode": ("qwen3-4b", 8, 4608, 2304, 128),
+}
+
+
+@pytest.mark.parametrize("cell", list(SERVING))
+def test_paged_prefill_compiles_serving(one_chip, cell):
+    """The chunk kernel at a cell's chunk, pool and table width, with
+    the tile the autotuner picks (all kv heads a grid step in both)."""
+    arch, _, max_seq, pages, chunk = SERVING[cell]
+    cfg = registry.get(arch)
+    hkv = cfg.n_kv_heads
+    choice = autotune.choose_prefill_blocks(chunk, hkv, cfg.n_heads // hkv,
+                                            cfg.head_dim_, PAGE)
+    assert choice is not None and choice.bh == hkv
+    shapes = _prefill_shapes(cfg, chunk, max_seq // PAGE, pages,
+                             cfg.n_layers)
+    found = _compiled_kernels(
+        lambda *a: paged_prefill(*a, layer=cfg.n_layers - 1, bh=choice.bh,
+                                 interpret=False), shapes, one_chip)
+    assert found == {"paged_prefill": 1}
+
+
+# (kv heads a grid step, query heads per kv head, chunk, head dim): both
+# cells' tiles, a smaller chunk and a tiny tile at dh 128; RecurrentGemma-
+# 2B's one kv head of 256, one head of xLSTM-1.3B's 512, and Granite's 8
+# kv heads of 64 (lanes half full): the lane and sublane padding counted
+VMEM_SHAPES = [(2, 8, 256, 128), (8, 4, 128, 128), (8, 4, 64, 128),
+               (2, 1, 64, 128), (1, 10, 128, 256), (1, 1, 64, 512),
+               (8, 2, 128, 64)]
+
+
+@pytest.mark.parametrize(
+    "bh,rep,chunk,dh", VMEM_SHAPES,
+    ids=[f"{b}-{r}-{c}" + ("" if d == 128 else f"-dh{d}")
+         for b, r, c, d in VMEM_SHAPES])
+def test_paged_prefill_vmem_model_bounds_mosaic(one_chip, monkeypatch, bh,
+                                                rep, chunk, dh):
+    """Mosaic compiles the chunk kernel with its scoped VMEM limited to
+    what ``paged_prefill_vmem_bytes`` counts: the count is an upper
+    bound of what Mosaic allocates, so the chooser never picks a tile
+    that runs out of VMEM."""
+    limit = autotune.paged_prefill_vmem_bytes(bh, rep, dh, PAGE, chunk)
+    call = pl.pallas_call
+
+    def limited(*a, **kw):
+        kw["compiler_params"] = pltpu.CompilerParams(vmem_limit_bytes=limit)
+        return call(*a, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", limited)
+    cfg = dataclasses.replace(CFG, n_heads=bh * rep, n_kv_heads=bh,
+                              head_dim=dh)
+    shapes = _prefill_shapes(cfg, chunk, NBLK, POOL_PAGES, 2)
+    found = _compiled_kernels(
+        lambda *a: paged_prefill.__wrapped__(*a, layer=1, bh=bh,
+                                             interpret=False),
+        shapes, one_chip)
+    assert found == {"paged_prefill": 1}
+
+
+def _abstract_serving(cfg, slots, pages, sharding):
+    """Packed parameters (data-free PTQ1.61, QKV and gate+up fused, as
+    the benchmark builds them) and the paged KV pool, as shapes."""
+    par = Parallel()
+    qcfg = QuantConfig(ratio=0.2, multiple=128, use_kernel=True)
+
+    def pack(p):
+        q = quantize_params_data_free({"stages": p["stages"]}, qcfg,
+                                      fuse=True)
+        return {**p, "stages": q["stages"]}
+
+    params = jax.eval_shape(pack, M.abstract_params(cfg, par))
+    caches = abstractify(M.init_paged_caches(cfg, par, slots, pages, PAGE))
+    put = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+    return par, put(params), put(caches)
+
+
+@pytest.mark.parametrize("cell", list(SERVING))
+def test_serving_steps_compile(one_chip, monkeypatch, cell):
+    """The whole decode and chunk steps of a cell, at its published widths
+    (two of its layers), slots, table width, pool and chunk: each holds
+    its kernels as ``tpu_custom_call``s, one a projection or layer."""
+    arch, slots, max_seq, pages, chunk = SERVING[cell]
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    cfg = dataclasses.replace(registry.get(arch),
+                              stages=(Stage(("dense",), 2),))
+    par, params, caches = _abstract_serving(cfg, slots, pages, one_chip)
+    nblk = max_seq // PAGE
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    step = functools.partial(M.decode_step_paged, cfg, par, max_seq=max_seq,
+                             use_kernel=True)
+    decode = tpu_kernels(jax.jit(step).lower(
+        params, i32(slots), i32(slots), caches, i32(slots, nblk),
+        i32(slots)).compile().as_text())
+    assert decode == {"mixed_matmul": 8, "paged_attention": 2}
+    step = functools.partial(M.prefill_step_paged, cfg, par,
+                             max_seq=max_seq, use_kernel=True)
+    chunked = tpu_kernels(jax.jit(step).lower(
+        params, i32(1, chunk), caches, i32(nblk), i32(nblk), i32(),
+        i32()).compile().as_text())
+    assert chunked == {"mixed_matmul": 8, "paged_prefill": 2}
