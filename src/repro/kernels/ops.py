@@ -129,13 +129,15 @@ def paged_attention_blocks(ps: int, hkv: int, rep: int, dh: int, nblk: int,
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, context_lens: jax.Array, *,
-                    window=None, softcap=None, ppcb=None) -> jax.Array:
-    """Paged flash-decode forward (see kernels.paged_attention); the
-    caller is expected to have consulted :func:`paged_attention_blocks`
-    first — this wrapper only pins the interpret mode."""
+                    layer, window=None, softcap=None,
+                    ppcb=None) -> jax.Array:
+    """Paged flash-decode forward over ``layer`` of the stacked pool (see
+    kernels.paged_attention); the caller is expected to have consulted
+    :func:`paged_attention_blocks` first — this wrapper only pins the
+    interpret mode."""
     return _paged_attn(q, k_pool, v_pool, block_tables, context_lens,
-                       window=window, softcap=softcap, ppcb=ppcb,
-                       interpret=INTERPRET)
+                       layer=layer, window=window, softcap=softcap,
+                       ppcb=ppcb, interpret=INTERPRET)
 
 
 def paged_prefill_blocks(c: int, ps: int, hkv: int, rep: int, dh: int,

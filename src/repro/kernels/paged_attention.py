@@ -1,10 +1,10 @@
 """Pallas TPU kernel: paged flash-decode attention over the shared KV pool.
 
 One pallas_call attends every decode slot's query against its own pages
-of the position-aligned pool ``(P, ps, hkv, dh)`` WITHOUT materializing
-the gathered ``(B, nblk*ps, hkv, dh)`` context in HBM — the win the
-paged serving path needs once PTQ1.61 weights stop dominating decode
-traffic (the KV cache does).
+of one layer of the stacked position-aligned pool ``(L, P, ps, hkv, dh)``
+WITHOUT materializing the gathered ``(B, nblk*ps, hkv, dh)`` context in
+HBM — the win the paged serving path needs once PTQ1.61 weights stop
+dominating decode traffic (the KV cache does).
 
 Mechanics:
 
@@ -34,7 +34,14 @@ Mechanics:
   table, below the window) are masked: their scores to ``-inf`` and
   their V rows to zero, since stale VMEM times a zero probability can
   still be NaN.
-* GQA without a relayout: the pool is viewed as ``(P, ps*hkv, dh)``
+* The stacked pool is the operand, and ``layer`` picks the pages to
+  DMA (``k_hbm.at[layer, page]``): a custom call cannot take a view into
+  a larger buffer, so handing it ``pool[layer]`` would make XLA copy that
+  layer's K and V out of the pool at every layer of every decode step.
+  ``layer`` rides in as a scalar-prefetch operand too, not as a static
+  argument, so every layer of a step shares one lowering of the kernel
+  (a static one lowers it once a layer, which multiplies set-up time).
+* GQA without a relayout: the pool is viewed as ``(L, P, ps*hkv, dh)``
   (a bitcast of its tiled HBM layout), so a block lands in VMEM as
   ``(ppcb*ps*hkv, dh)`` rows, token-major and head-minor.  All ``hq``
   queries meet all rows in one pair of MXU dots, and a score whose row
@@ -130,9 +137,9 @@ def attn_block_counts(context_lens, ps: int, ppcb: int, nblk: int,
     return int((end - first).sum()), len(context_lens) * nb
 
 
-def _kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
-            state, mask_ref, m_ref, l_ref, acc_ref, *, ps, ppcb, nblk, hkv,
-            rep, sm_scale, window, softcap):
+def _kernel(layer_ref, bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
+            vbuf, sems, state, mask_ref, m_ref, l_ref, acc_ref, *, ps, ppcb,
+            nblk, hkv, rep, sm_scale, window, softcap):
     b, i = pl.program_id(0), pl.program_id(1)
     nrows, nb = pl.num_programs(0), pl.num_programs(1)
     tile = ppcb * ps
@@ -158,13 +165,15 @@ def _kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
         page = bt_ref[bi * nblk + jnp.minimum(j, nblk - 1)]
         return j, page, fetched(j, page, len_ref[bi])
 
+    layer = layer_ref[0]
+
     def copies(page, t, slot):
         dst = pl.ds(t * rows, rows)
         page = jnp.maximum(page, 0)
-        return (pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot, dst],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot, dst],
-                                      sems.at[1, slot]))
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                      kbuf.at[slot, dst], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, page],
+                                      vbuf.at[slot, dst], sems.at[1, slot]))
 
     # the per-page loops are unrolled: a rolled loop's scalar overhead
     # costs about as much as the copies it starts
@@ -285,17 +294,20 @@ def _kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
                                              "interpret"))
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, context_lens: jax.Array, *,
+                    layer,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     ppcb: Optional[int] = None,
                     interpret: bool = True) -> jax.Array:
     """Flash-decode over pool pages.
 
-    q (B, hq, dh); k_pool/v_pool (P, ps, hkv, dh_pool); block_tables
-    (B, nblk) int32 page ids (-1 = unassigned); context_lens (B,) int32
-    live tokens per request (0 = inactive row -> zero output).  Returns
-    (B, hq, dh) f32.  ``ppcb`` (pool pages per compute block) defaults
-    to the autotuner's pick.
+    q (B, hq, dh); k_pool/v_pool (L, P, ps, hkv, dh_pool), the stacked
+    pool of which ``layer`` (an int or an int32 scalar) is read (a
+    single layer's pool is passed as ``pool[None]`` with ``layer=0``);
+    block_tables (B, nblk) int32 page ids (-1 = unassigned);
+    context_lens (B,) int32 live tokens per request (0 = inactive row ->
+    zero output).  Returns (B, hq, dh) f32.  ``ppcb`` (pool pages per
+    compute block) defaults to the autotuner's pick.
 
     ``dh_pool`` may exceed q's logical ``dh`` (lane-padded pools for
     archs with ``dh`` off the 128-lane TPU tile —
@@ -305,7 +317,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     nothing to q·k and the padded V columns never survive the slice.
     """
     b, hq, dh = q.shape
-    num_pages, ps, hkv, dh_pool = k_pool.shape
+    n_layers, num_pages, ps, hkv, dh_pool = k_pool.shape
     nblk = block_tables.shape[1]
     rep = hq // hkv
     if hq % hkv:
@@ -327,11 +339,11 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     ppcb = min(ppcb, nblk)
     rows = ppcb * ps * hkv
 
-    def q_map(bi, i, bt, lens):
+    def q_map(bi, i, lay, bt, lens):
         return (bi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, -(-nblk // ppcb)),
         in_specs=[
             pl.BlockSpec((1, hq, dh_pool), q_map),
@@ -350,8 +362,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             pltpu.VMEM((hq, dh_pool), jnp.float32),       # weighted-V acc
         ],
     )
-    # (P, ps, hkv, dh) -> (P, ps*hkv, dh): a bitcast of the tiled pool
-    pool_rows = (num_pages, ps * hkv, dh_pool)
+    # (L, P, ps, hkv, dh) -> (L, P, ps*hkv, dh): a bitcast of the tiled pool
+    pool_rows = (n_layers, num_pages, ps * hkv, dh_pool)
     out = pl.pallas_call(
         functools.partial(_kernel, ps=ps, ppcb=ppcb, nblk=nblk, hkv=hkv,
                           rep=rep, sm_scale=sm_scale, window=window,
@@ -363,7 +375,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 2),
         name="paged_attention", interpret=interpret,
-    )(block_tables.reshape(-1).astype(jnp.int32),
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      block_tables.reshape(-1).astype(jnp.int32),
       context_lens.astype(jnp.int32), q, k_pool.reshape(pool_rows),
       v_pool.reshape(pool_rows))
     return out[..., :dh]

@@ -423,9 +423,10 @@ def attention_decode_paged(cfg: ArchConfig, par: Parallel, p: Tree,
     The read has two paths, mirroring ``ops.mixed_matmul``:
 
     * **Pallas flash-decode kernel** (default on feasible shapes, needs
-      ``lengths``): walks each request's pages straight out of the pool
-      with scalar-prefetched block tables — per-token KV traffic scales
-      with the LIVE context, and no (B, nblk*ps, hkv, dh) gather buffer
+      ``lengths``): walks each request's pages of ``layer`` straight out
+      of the stacked pool with scalar-prefetched block tables — per-token
+      KV traffic scales with the LIVE context, and neither a (B,
+      nblk*ps, hkv, dh) gather buffer nor a copy of the layer's pool
       ever exists in HBM (``repro.kernels.paged_attention``).
     * **XLA gather reference/fallback**: gathers the request's pages
       into a dense context and masks by the implied positions — the
@@ -460,8 +461,8 @@ def attention_decode_paged(cfg: ArchConfig, par: Parallel, p: Tree,
                                          pool_dh=dh_pool)
               if use_kernel and lengths is not None else None)
     if choice is not None:
-        o = ops.paged_attention(q[:, 0], ck[layer], cv[layer],
-                                block_tables, lengths, window=window,
+        o = ops.paged_attention(q[:, 0], ck, cv, block_tables, lengths,
+                                layer=layer, window=window,
                                 softcap=cfg.logit_softcap, ppcb=choice.ppcb)
         o = o[:, None]                                   # (B, 1, hq, dh)
     else:

@@ -402,16 +402,21 @@ def stage_step_paged(cfg: ArchConfig, par: Parallel, stage: Stage,
                      caches: Tree, block_tables: jax.Array,
                      context_lens=None, max_seq: int = 0,
                      use_kernel: bool = True):
-    """Always unrolled over layers: each layer's page writes are in-place
-    slot scatters addressed into the stacked pool; a scan would round-trip
-    the whole (L, P, ps, H, dh) pool through the carry every layer.
+    """Always unrolled over layers: each layer's page writes are slot
+    scatters addressed into the stacked pool, and the kernel reads its
+    layer out of the stacked pool too; a scan would round-trip the whole
+    (L, P, ps, H, dh) pool through the carry every layer.  The writes are
+    in place only where the caller donates the pool
+    (``runtime.engine.paged_steps``); otherwise XLA copies it whole into
+    the output buffer first.
 
     Fully-inactive ticks (every block-table row -1, i.e. no slot owns a
     page) short-circuit via ``lax.cond``: the whole layer walk — QKV
     projections, page scatters, attention, MLPs — is skipped on device
-    and x/caches pass through untouched.  Per-row inactivity inside a
-    live batch is handled downstream (the kernel zero-fills rows with
-    ``context_lens == 0``; the XLA path masks their pages)."""
+    and x/caches pass through untouched (with the pool donated, the
+    conditional aliases it and copies nothing).  Per-row inactivity
+    inside a live batch is handled downstream (the kernel zero-fills
+    rows with ``context_lens == 0``; the XLA path masks their pages)."""
 
     def walk(args):
         x, caches = args
@@ -464,8 +469,9 @@ def stage_prefill_step_paged(cfg: ArchConfig, par: Parallel, stage: Stage,
                              use_kernel: bool = True):
     """Chunk-prefill walk over a stage: unrolled over layers exactly
     like :func:`stage_step_paged`, so each layer's fused scatter+attend
-    updates the stacked pool in place instead of round-tripping it
-    through a scan carry."""
+    writes into the stacked pool instead of round-tripping it through a
+    scan carry — in place where the caller donates the pool, as
+    :func:`stage_step_paged` says."""
     cur = list(caches)
     for layer in range(stage.repeats):
         lp = jax.tree.map(lambda a: a[layer], sparams)

@@ -127,6 +127,27 @@ class Request:
 # ---------------------------------------------------------------------------
 # Cache backends
 # ---------------------------------------------------------------------------
+# Every step that takes the KV caches and returns them new is jitted with
+# the caches donated.  A backend stores each result back over the only
+# reference it holds, so the program updates the pool in place; without
+# donation XLA copies the whole pool into a fresh output buffer at every
+# call and device memory holds it twice.
+def paged_steps(cfg: ArchConfig, par: Parallel, *, max_seq: int,
+                use_kernel: bool = True):
+    """The paged decode step (pool: argument 3) and chunk-prefill step
+    (pool: argument 2), jitted as the paged backend serves them.  The
+    chunk step takes start/length as traced scalars, so every chunk of
+    every prompt hits the ONE compiled (1, prefill_chunk) shape — no
+    bucket ladder."""
+    decode = jax.jit(functools.partial(
+        M.decode_step_paged, cfg, par, max_seq=max_seq,
+        use_kernel=use_kernel), donate_argnums=3)
+    chunk = jax.jit(functools.partial(
+        M.prefill_step_paged, cfg, par, max_seq=max_seq,
+        use_kernel=use_kernel), donate_argnums=2)
+    return decode, chunk
+
+
 class _ContiguousBackend:
     """Legacy per-slot ring-buffer caches: (B, max_seq) regions."""
 
@@ -137,8 +158,10 @@ class _ContiguousBackend:
         cache_decl = M.init_caches(eng.cfg, eng.par, eng.n_slots, eng.max_seq)
         self.caches = materialize(cache_decl, jax.random.PRNGKey(0))
         self._decode = jax.jit(functools.partial(
-            M.decode_step, eng.cfg, eng.par, max_seq=eng.max_seq))
-        self._splice = jax.jit(functools.partial(M.splice_prefill, eng.cfg))
+            M.decode_step, eng.cfg, eng.par, max_seq=eng.max_seq),
+            donate_argnums=3)
+        self._splice = jax.jit(functools.partial(M.splice_prefill, eng.cfg),
+                               donate_argnums=0)
 
     def free_pages(self) -> Optional[int]:
         return None                      # slots pre-reserve max_seq
@@ -187,18 +210,12 @@ class _PagedBackend:
                                          pool_pages, page_size,
                                          dtype=cache_dtype)
         self.caches = materialize(cache_decl, jax.random.PRNGKey(0))
-        self._decode = jax.jit(functools.partial(
-            M.decode_step_paged, eng.cfg, eng.par, max_seq=eng.max_seq,
-            use_kernel=use_kernel))
+        self._decode, self._chunk_step = paged_steps(
+            eng.cfg, eng.par, max_seq=eng.max_seq, use_kernel=use_kernel)
         self._splice = jax.jit(functools.partial(
-            M.splice_prefill_paged, eng.cfg))
-        self._copy = jax.jit(functools.partial(M.copy_pages, eng.cfg))
-        # chunked-prefill step (one request, one chunk): start/length
-        # ride as traced scalars so every chunk of every prompt hits the
-        # ONE compiled (1, prefill_chunk) shape — no bucket ladder
-        self._chunk_step = jax.jit(functools.partial(
-            M.prefill_step_paged, eng.cfg, eng.par, max_seq=eng.max_seq,
-            use_kernel=use_kernel))
+            M.splice_prefill_paged, eng.cfg), donate_argnums=0)
+        self._copy = jax.jit(functools.partial(M.copy_pages, eng.cfg),
+                             donate_argnums=0)
         self.prefill_chunk_calls = 0
         self.prefill_kv_read_bytes = 0
         # (ppcb, nblk, window) of the decode kernel's calls, for the

@@ -258,6 +258,47 @@ def test_engine_lowered_steps_shapes(subject):
     assert logits["prefill_chunk"].shape == (1, cfg.vocab)
 
 
+def test_engine_donates_the_pool(subject):
+    """The chunk and decode steps take the pool donated: after a tick
+    that ran only a chunk, and after one that ran only a decode step,
+    the pool arrays held before the tick are deleted, and the request
+    still finishes from the pool the steps returned."""
+    cfg, params = subject
+    eng = Engine(cfg, PAR, params, n_slots=2, max_seq=64, paged=True,
+                 page_size=8, chunked_prefill=True, prefill_chunk=16)
+    req = eng.submit(np.arange(1, 21, dtype=np.int32), max_new=4)
+    be = eng.backend
+
+    def tick_and_check(chunks):
+        before = jax.tree.leaves(be.caches)
+        calls = be.prefill_chunk_calls
+        eng.tick()
+        assert be.prefill_chunk_calls == calls + chunks
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in jax.tree.leaves(be.caches))
+
+    tick_and_check(1)             # first of two chunks: no decode yet
+    assert not req.out_tokens
+    eng.tick()                    # last chunk, then the first decode
+    tick_and_check(0)             # a decode step alone
+    eng.run()
+    assert len(req.out_tokens) == 4
+
+
+def test_contiguous_engine_donates_its_caches(subject):
+    """The contiguous backend's splice and decode step take the caches
+    donated too: a tick that admits and decodes deletes the caches held
+    before it."""
+    cfg, params = subject
+    eng = Engine(cfg, PAR, params, n_slots=2, max_seq=64)
+    req = eng.submit(np.arange(1, 11, dtype=np.int32), max_new=3)
+    before = jax.tree.leaves(eng.backend.caches)
+    eng.tick()
+    assert all(a.is_deleted() for a in before)
+    eng.run()
+    assert len(req.out_tokens) == 3
+
+
 def test_engine_chunked_vs_whole_greedy_identity(subject):
     """Engine-level: ragged prompts, f32 pools — greedy outputs of the
     chunked engine are bit-identical to the whole-prompt engine's."""

@@ -64,8 +64,8 @@ PPCB = [1, 2, 3, 4, None]
 def _run(q, k_pool, v_pool, bt, lens, ppcb, **kw):
     nblk = np.asarray(bt).shape[1]
     return np.asarray(ops.paged_attention(
-        q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens),
-        ppcb=nblk if ppcb is None else ppcb, **kw))
+        q, k_pool[None], v_pool[None], jnp.asarray(bt), jnp.asarray(lens),
+        layer=0, ppcb=nblk if ppcb is None else ppcb, **kw))
 
 
 @pytest.mark.parametrize("ppcb", PPCB)
@@ -171,6 +171,31 @@ def test_kernel_nan_in_unfetched_pages(rng, ppcb):
     np.testing.assert_array_equal(out[2], 0.0)
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_kernel_reads_only_its_layer(rng, layer):
+    """The kernel takes the stacked pool and DMAs pages of ``layer``
+    alone: the other layers hold NaN or 1e30, and the output matches the
+    XLA gather of that layer by itself."""
+    b, num_pages, ps, hkv, dh, nblk = 3, 12, 8, 2, 16, 4
+    q = jnp.asarray(rng.normal(size=(b, hkv * 2, dh)), jnp.float32)
+    k_pool, v_pool = _pool_state(rng, num_pages, ps, hkv, dh, jnp.float32)
+    fill = (jnp.nan, 1e30, jnp.nan)
+
+    def stack(pool):
+        return jnp.stack([pool if i == layer else jnp.full_like(pool, f)
+                          for i, f in enumerate(fill)])
+    bt = np.asarray([[3, 7, -1, -1],
+                     [0, 1, 2, 5],
+                     [9, 4, 11, -1]], np.int32)
+    lens = np.asarray([13, 30, 17], np.int32)
+    out = np.asarray(ops.paged_attention(
+        q, stack(k_pool), stack(v_pool), jnp.asarray(bt), jnp.asarray(lens),
+        layer=layer, ppcb=2))
+    assert np.isfinite(out).all()
+    ref = _oracle(q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens))
+    np.testing.assert_allclose(out, ref, rtol=2e-2, atol=1e-5)
+
+
 def test_kernel_bh_sweep_block_size_independent(rng):
     """Results must not depend on the pages per compute block, with four
     kv heads interleaved in each block's rows."""
@@ -180,7 +205,8 @@ def test_kernel_bh_sweep_block_size_independent(rng):
     bt = np.asarray([[0, 1, 2], [3, 4, -1]], np.int32)
     lens = np.asarray([20, 11], np.int32)
     outs = [np.asarray(ops.paged_attention(
-        q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(lens), ppcb=ppcb))
+        q, k_pool[None], v_pool[None], jnp.asarray(bt), jnp.asarray(lens),
+        layer=0, ppcb=ppcb))
         for ppcb in (1, 2, 3)]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], rtol=1e-6, atol=1e-6)
@@ -470,14 +496,14 @@ def test_kernel_padded_pool_matches_unpadded(rng):
                      [0, 1, 2, 5],
                      [-1, -1, -1, -1]], np.int32)
     lens = np.asarray([13, 30, 0], np.int32)
-    out = np.asarray(ops.paged_attention(q, k_pool, v_pool,
+    out = np.asarray(ops.paged_attention(q, k_pool[None], v_pool[None],
                                          jnp.asarray(bt),
-                                         jnp.asarray(lens)))
+                                         jnp.asarray(lens), layer=0))
     pad = ((0, 0), (0, 0), (0, 0), (0, 16))        # dh 16 -> 32 pool tile
-    out_p = np.asarray(ops.paged_attention(q, jnp.pad(k_pool, pad),
-                                           jnp.pad(v_pool, pad),
+    out_p = np.asarray(ops.paged_attention(q, jnp.pad(k_pool, pad)[None],
+                                           jnp.pad(v_pool, pad)[None],
                                            jnp.asarray(bt),
-                                           jnp.asarray(lens)))
+                                           jnp.asarray(lens), layer=0))
     assert out_p.shape == out.shape                # sliced back to dh
     np.testing.assert_allclose(out_p, out, rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(out_p[2], 0.0)   # inactive row intact

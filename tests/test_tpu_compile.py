@@ -12,7 +12,7 @@ to XLA fails too.  The topology is described inside the module fixture
 (never at import): only the worker that runs this file loads libtpu.
 """
 import dataclasses
-import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,7 @@ from repro.launch.hlo_analysis import tpu_kernels
 from repro.models import model as M
 from repro.models.common import Parallel
 from repro.models.param import abstractify
+from repro.runtime.engine import paged_steps
 
 CFG = registry.get("qwen2.5-3b")
 DECODE_M = 4                  # chip_smoke's decode slots
@@ -98,11 +99,12 @@ def test_mixed_matmul_compiles(one_chip, proj, m):
 
 def test_paged_attention_compiles(one_chip):
     shapes = [((DECODE_M, HQ, DH), jnp.bfloat16),
-              ((POOL_PAGES, PAGE, HKV, DH), jnp.bfloat16),
-              ((POOL_PAGES, PAGE, HKV, DH), jnp.bfloat16),
+              ((1, POOL_PAGES, PAGE, HKV, DH), jnp.bfloat16),
+              ((1, POOL_PAGES, PAGE, HKV, DH), jnp.bfloat16),
               ((DECODE_M, NBLK), jnp.int32), ((DECODE_M,), jnp.int32)]
     found = _compiled_kernels(
-        lambda *a: paged_attention(*a, interpret=False), shapes, one_chip)
+        lambda *a: paged_attention(*a, layer=0, interpret=False), shapes,
+        one_chip)
     assert found == {"paged_attention": 1}
 
 
@@ -121,11 +123,12 @@ def test_paged_attention_compiles_serving(one_chip, cell):
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     choice = autotune.choose_paged_blocks(hkv, hq // hkv, dh, PAGE, nblk)
     assert choice is not None
-    pool = ((pages, PAGE, hkv, dh), jnp.bfloat16)
+    pool = ((1, pages, PAGE, hkv, dh), jnp.bfloat16)
     shapes = [((b, hq, dh), jnp.bfloat16), pool, pool,
               ((b, nblk), jnp.int32), ((b,), jnp.int32)]
     found = _compiled_kernels(
-        lambda *a: paged_attention(*a, ppcb=choice.ppcb, interpret=False),
+        lambda *a: paged_attention(*a, layer=0, ppcb=choice.ppcb,
+                                   interpret=False),
         shapes, one_chip)
     assert found == {"paged_attention": 1}
 
@@ -230,28 +233,120 @@ def _abstract_serving(cfg, slots, pages, sharding):
     return par, put(params), put(caches)
 
 
-@pytest.mark.parametrize("cell", list(SERVING))
-def test_serving_steps_compile(one_chip, monkeypatch, cell):
-    """The whole decode and chunk steps of a cell, at its published widths
-    (two of its layers), slots, table width, pool and chunk: each holds
-    its kernels as ``tpu_custom_call``s, one a projection or layer."""
+# a v5e core's VMEM: XLA may stage a smaller pool there whole
+VMEM_BYTES = 128 * 2**20
+
+# what may produce a pool-shaped value without copying the pool: views,
+# plumbing and the conditional that passes the pool through (besides the
+# kernels and the scatters, let through by the test below)
+IN_PLACE = {"parameter", "get-tuple-element", "tuple", "bitcast",
+            "conditional"}
+INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*?)\s"
+                   r"([a-z][\w\-]*)\(")
+ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _instructions(hlo):
+    """(result shapes, opcode, line) of every instruction in ``hlo``."""
+    for line in hlo.splitlines():
+        m = INSTR.match(line)
+        if m:
+            shapes = {tuple(int(d) for d in a.split(",") if d)
+                      for a in ARRAY.findall(m.group(2))}
+            yield shapes, m.group(3), line
+
+
+def _serving_depth(cfg, pages):
+    """The fewest layers, two at least, at which one pool leaf outgrows
+    VMEM, as it does at every cell's full depth."""
+    hkv, dh = cfg.n_kv_heads, cfg.head_dim_
+    layer = (pages + 1) * PAGE * hkv * dh * 2               # bf16
+    return max(2, VMEM_BYTES // layer + 1)
+
+
+def _compile_serving_steps(cell, sharding):
+    """A cell's decode and chunk steps as the engine jits them
+    (``paged_steps``, the pool donated), compiled at its published
+    widths, slots, table width, pool and chunk: (layers, pool shape,
+    {step: compiled HLO text})."""
     arch, slots, max_seq, pages, chunk = SERVING[cell]
-    monkeypatch.setattr(ops, "INTERPRET", False)
-    cfg = dataclasses.replace(registry.get(arch),
-                              stages=(Stage(("dense",), 2),))
-    par, params, caches = _abstract_serving(cfg, slots, pages, one_chip)
-    nblk = max_seq // PAGE
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
-                                              sharding=one_chip)
-    step = functools.partial(M.decode_step_paged, cfg, par, max_seq=max_seq,
-                             use_kernel=True)
-    decode = tpu_kernels(jax.jit(step).lower(
-        params, i32(slots), i32(slots), caches, i32(slots, nblk),
-        i32(slots)).compile().as_text())
-    assert decode == {"mixed_matmul": 8, "paged_attention": 2}
-    step = functools.partial(M.prefill_step_paged, cfg, par,
-                             max_seq=max_seq, use_kernel=True)
-    chunked = tpu_kernels(jax.jit(step).lower(
-        params, i32(1, chunk), caches, i32(nblk), i32(nblk), i32(),
-        i32()).compile().as_text())
-    assert chunked == {"mixed_matmul": 8, "paged_prefill": 2}
+    base = registry.get(arch)
+    n = _serving_depth(base, pages)
+    cfg = dataclasses.replace(base, stages=(Stage(("dense",), n),))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "INTERPRET", False)
+        par, params, caches = _abstract_serving(cfg, slots, pages, sharding)
+        nblk = max_seq // PAGE
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                  sharding=sharding)
+        decode, chunk_step = paged_steps(cfg, par, max_seq=max_seq,
+                                         use_kernel=True)
+        lowered = {
+            "decode": decode.lower(params, i32(slots), i32(slots), caches,
+                                   i32(slots, nblk), i32(slots)),
+            "prefill_chunk": chunk_step.lower(
+                params, i32(1, chunk), caches, i32(nblk), i32(nblk), i32(),
+                i32())}
+        hlo = {k: v.compile().as_text() for k, v in lowered.items()}
+    return n, caches[0][0]["k"].shape, hlo
+
+
+@pytest.fixture(scope="module")
+def serving_steps(one_chip):
+    """Each cell's compiled steps, compiled once for the tests below."""
+    compiled = {}
+
+    def get(cell):
+        if cell not in compiled:
+            compiled[cell] = _compile_serving_steps(cell, one_chip)
+        return compiled[cell]
+    return get
+
+
+@pytest.mark.parametrize("cell", list(SERVING))
+def test_serving_steps_compile(serving_steps, cell):
+    """The whole decode and chunk steps of a cell, at its published widths
+    (as many of its layers as outgrow VMEM), slots, table width, pool and
+    chunk: each holds its kernels as ``tpu_custom_call``s, one a
+    projection or layer."""
+    n, _, hlo = serving_steps(cell)
+    assert tpu_kernels(hlo["decode"]) == {"mixed_matmul": 4 * n,
+                                          "paged_attention": n}
+    assert tpu_kernels(hlo["prefill_chunk"]) == {"mixed_matmul": 4 * n,
+                                                 "paged_prefill": n}
+    # the layer is an operand of the attention kernel, so every layer's
+    # call is one lowering of it (a static layer lowers it once a layer)
+    bodies = {re.search(r"backend_config=(\{.*?\})(?:,|$)", line).group(1)
+              for _, op, line in _instructions(hlo["decode"])
+              if line.lstrip().startswith("%paged_attention")}
+    assert len(bodies) == 1
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("cell", list(SERVING))
+def test_serving_steps_update_the_pool_in_place(serving_steps, cell, step):
+    """The served steps take the pool donated and never copy it: its K
+    and V parameters alias outputs, and nothing but views, the
+    conditional, scatters and the kernels yields a value of the stacked
+    pool's shape, one layer's, or the kernel's per-layer row view — no
+    ``copy`` and no ``slice`` of the pool, whole or by layer."""
+    _, pool, hlo = serving_steps(cell)
+    hlo = hlo[step]
+    _, p, ps, hkv, dh = pool
+    pooled = {pool, (1, p, ps, hkv, dh), (p, ps, hkv, dh), (p, ps * hkv, dh)}
+    params = [re.search(r"parameter\((\d+)\)", line).group(1)
+              for shapes, op, line in _instructions(hlo)
+              if op == "parameter" and pool in shapes and "caches" in line]
+    assert len(params) == 2                               # K and V
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+    aliased = set(re.findall(r"\((\d+), \{\}, may-alias\)",
+                             alias.group(1) if alias else ""))
+    assert set(params) <= aliased
+    copies = [line.strip()[:160] for shapes, op, line in _instructions(hlo)
+              if shapes & pooled and op not in IN_PLACE
+              and 'custom_call_target="tpu_custom_call"' not in line
+              and not (op == "scatter" or (op == "fusion"
+                                           and '/scatter"' in line))]
+    assert not copies
+    assert tpu_kernels(hlo).get(
+        "paged_attention" if step == "decode" else "paged_prefill")
