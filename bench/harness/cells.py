@@ -2,14 +2,18 @@
 
 ``BENCHMARK.json`` at the checkout root names each cell's config and
 traffic; the files are ``bench/configs/<config>.json`` and
-``bench/traffic/<traffic>.json``.  A per-layer metric is
-``bench/metrics/<metric>.py``.  Adding any of them is adding
-files and entries; no existing file changes.
+``bench/traffic/<traffic>.json``.  A configuration names its model
+family under ``"family"``, which is ``bench/families/<family>.py``, and
+its plain reference under ``"reference"``, which is
+``bench/references/<reference>.py``.  A per-layer metric is
+``bench/metrics/<metric>.py``.  Adding any of them is adding files and
+entries; no existing file changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
@@ -24,6 +28,22 @@ class CellError(ValueError):
 def load_json(path: Path) -> Dict[str, Any]:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(bench_dir: Path, kind: str, name: str):
+    """``<bench_dir>/<kind>/<name>.py``, loaded as a fresh module."""
+    path = Path(bench_dir) / kind / f"{name}.py"
+    if not path.is_file():
+        raise CellError(f"no {kind} module {path}")
+    mod_name = f"bench_{kind}_" + "".join(
+        c if c.isalnum() else "_" for c in name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered before it runs, as an import would be: a dataclass looks
+    # its module up while the class is made
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _applies(metric: Dict[str, Any], cell: str) -> bool:
@@ -50,6 +70,8 @@ class Benchmark:
         if not path.is_file():
             raise CellError(f"no configuration file {path}")
         spec = load_json(path)
+        if "family" not in spec:
+            raise CellError(f"configuration {path} names no family")
         spec.setdefault("name", name)
         return spec
 
@@ -67,21 +89,14 @@ class Benchmark:
     def per_layer(self, cell: str) -> List[Dict[str, Any]]:
         return [m for m in self.spec["per_layer"] if _applies(m, cell)]
 
-    def _module(self, kind: str, name: str):
-        path = self.dir / kind / f"{name}.py"
-        if not path.is_file():
-            raise CellError(f"no {kind} module {path}")
-        mod_name = f"bench_{kind}_" + "".join(
-            c if c.isalnum() else "_" for c in name)
-        spec = importlib.util.spec_from_file_location(mod_name, path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
     def metric_reader(self, name: str):
         """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-        return self._module("metrics", name).read
+        return load_module(self.dir, "metrics", name).read
 
     def reference(self, name: str):
         """The plain reference module ``bench/references/<name>.py``."""
-        return self._module("references", name)
+        return load_module(self.dir, "references", name)
+
+    def family(self, name: str):
+        """The model family module ``bench/families/<name>.py``."""
+        return load_module(self.dir, "families", name)

@@ -3,15 +3,15 @@ result line.
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up builds the packed weights from the seed (``weights.py``), the
-program's paged ``Engine`` with chunked prefill and the Pallas kernels,
-compiles its decode and chunk steps (failing when ``mixed_matmul``,
-``paged_attention`` or ``paged_prefill`` is not a ``tpu_custom_call`` in
-the step it belongs to), warms both up with one request, and, where the
-mix asks, admits the first clients' requests so the window starts with
-every slot decoding.  The window then drives ``Engine.tick()`` for
-``--seconds``, offering the mix's requests and stamping each token when
-its tick returns.  ``--trace 1`` records the window with the profiler
+Set-up loads the configuration's model family (``bench/families``),
+builds the packed weights from the seed with it, the program's paged
+``Engine`` with chunked prefill and the Pallas kernels, compiles its
+decode and chunk steps (failing when a kernel the family's ``KERNELS``
+names is not a ``tpu_custom_call`` in the step it belongs to), warms
+both up with one request, and, where the mix asks, admits the first
+clients' requests so the window starts with every slot decoding.  The
+window then drives ``Engine.tick()`` for ``--seconds``, offering the
+mix's requests and stamping each token when its tick returns.  ``--trace 1`` records the window with the profiler
 and reports the per-layer metrics instead of the end-to-end ones.  After
 the window the sampled finished requests go through the plain
 reference (``check.py``).
@@ -30,13 +30,11 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from harness import check, e2e, spec as spec_mod, trace as trace_mod
+from harness import check, e2e, trace as trace_mod
 from harness import traffic as traffic_mod
 from harness.cells import BENCH_DIR, Benchmark
 from harness.peaks import peaks
 
-KERNELS = {"decode": ("mixed_matmul", "paged_attention"),
-           "prefill_chunk": ("mixed_matmul", "paged_prefill")}
 CACHE_DIR = BENCH_DIR / ".jax_cache"
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/compilation_cache/cache_retrieval_time_sec")
@@ -80,13 +78,15 @@ def tpu_kernels(hlo: str) -> Dict[str, int]:
     return out
 
 
-def check_kernels(engine) -> Dict[str, Dict[str, int]]:
+def check_kernels(engine, kernels: Dict[str, Tuple[str, ...]]
+                  ) -> Dict[str, Dict[str, int]]:
     """Compile the engine's decode and chunk steps and fail unless each
-    holds its kernels as ``tpu_custom_call``."""
+    holds the ``kernels`` named for it (a family's ``KERNELS``) as
+    ``tpu_custom_call``."""
     found = {}
     for step, lowered in engine.backend.lowered_steps(engine.params).items():
         found[step] = tpu_kernels(lowered.compile().as_text())
-        for name in KERNELS[step]:
+        for name in kernels[step]:
             if not found[step].get(name):
                 raise KernelMissing(f"{name} is not a tpu_custom_call in "
                                     f"the compiled {step} step")
@@ -185,22 +185,23 @@ def _enable_cache() -> str:
 
 
 def setup(bench: Benchmark, wl: Dict, seed: int, seconds: float,
-          devices) -> Tuple[Any, Client, Dict, Dict, Dict[str, float]]:
-    """Weights, engine, compile and kernel check, warm-up, context."""
+          devices) -> Tuple[Any, Client, Dict, Any, Dict, Dict[str, float]]:
+    """Weights, engine, compile and kernel check, warm-up, context.
+    Returns the engine, the client, the configuration, its family module,
+    the mix and the set-up's parts."""
     import jax
     from repro.models.common import Parallel
     from repro.runtime.engine import Engine
 
-    from harness.weights import build_params
-
     spec = bench.config(wl["config"])
+    family = bench.family(spec["family"])
     mix = bench.traffic(wl["traffic"])
-    dm = spec_mod.dims(spec)
+    dm = family.dims(spec)
     dep = spec["deployment"]
     parts: Dict[str, float] = {}
 
     t = time.perf_counter()
-    cfg, params = build_params(spec, seed)
+    cfg, params = family.build_params(spec, seed)
     jax.block_until_ready(params)
     parts["weights_s"] = time.perf_counter() - t
 
@@ -212,10 +213,9 @@ def setup(bench: Benchmark, wl: Dict, seed: int, seconds: float,
                     pool_pages=traffic_mod.pool_pages(
                         mix, dm.kv_bytes_per_token(), page),
                     paged_kernel=True, chunked_prefill=True,
-                    prefill_chunk=int(dep["prefill_chunk"]),
-                    time_phases=False)
+                    prefill_chunk=int(dep["prefill_chunk"]))
     del params
-    found = check_kernels(engine)
+    found = check_kernels(engine, family.KERNELS)
     parts["compile_s"] = time.perf_counter() - t
     log(f"kernels per compiled step: {json.dumps(found)}")
 
@@ -244,7 +244,7 @@ def setup(bench: Benchmark, wl: Dict, seed: int, seconds: float,
             client.tick()
         engine.prefill_chunks_per_tick = per_tick
     parts["context_s"] = time.perf_counter() - t
-    return engine, client, spec, mix, parts
+    return engine, client, spec, family, mix, parts
 
 
 def drive(engine, client: Client, mix: Dict, seconds: float) -> Window:
@@ -316,8 +316,11 @@ def drive(engine, client: Client, mix: Dict, seconds: float) -> Window:
 
 @dataclass
 class Context:
-    """What a per-layer metric reader gets."""
+    """What a per-layer metric reader gets: ``family`` is the
+    configuration's family module, whose work counts the rooflines read,
+    and ``dims`` its sizes of the configuration."""
     dims: Any
+    family: Any
     spec: Dict
     window: Window
     engine_metrics: Any
@@ -331,14 +334,23 @@ class Context:
         self.derived[name] = text
         log(f"{name}: {text}")
 
-    def decode_rows(self) -> List[int]:
+    def decode_rows(self, steps: Optional[int] = None) -> List[int]:
         """Context length of every decoded (not prefill-sampled) token the
-        window stamped."""
+        window stamped.  With ``steps``, only those of the window's first
+        ``steps`` decode ticks (the tokens of one tick share its stamp):
+        the decode steps the trace holds, which stops before the window's
+        end once it reaches the profiler's cap of about 450 MB."""
         w = self.window
+        last = float("inf")
+        if steps is not None:
+            ticks = sorted({t for rec in w.records.values()
+                            for t in rec.stamps[1:] if w.t0 <= t <= w.t1})
+            if steps < len(ticks):
+                last = ticks[steps - 1] if steps else float("-inf")
         out = []
         for idx, rec in w.records.items():
             for j, t in enumerate(rec.stamps):
-                if j >= 1 and w.t0 <= t <= w.t1:
+                if j >= 1 and w.t0 <= t <= min(w.t1, last):
                     out.append(rec.prompt_len + j)
         return out
 
@@ -391,8 +403,8 @@ def serve(bench: Benchmark, wl: Dict, seed: int, seconds: float,
     readings tool's second witness)."""
     import jax
 
-    engine, client, spec, mix, parts = setup(bench, wl, seed, seconds,
-                                             devices)
+    engine, client, spec, family, mix, parts = setup(bench, wl, seed,
+                                                     seconds, devices)
     setup_s = time.time() - t_process
     log("set-up: " + ", ".join(f"{k} {v:.3f}" for k, v in parts.items())
         + f", total {setup_s:.3f} s")
@@ -438,7 +450,8 @@ def serve(bench: Benchmark, wl: Dict, seed: int, seconds: float,
         busy_s, window_s, breakdown = _device_summary(red)
         red["busy_s"], red["window_s"] = busy_s, window_s
         device["busy_s"], device["window_s"] = busy_s, window_s
-        ctx = Context(dims=spec_mod.dims(spec), spec=spec, window=w, engine_metrics=engine.metrics, trace=red,
+        ctx = Context(dims=family.dims(spec), family=family, spec=spec,
+                      window=w, engine_metrics=engine.metrics, trace=red,
                       peaks=peaks(dev.device_kind))
         for m in bench.per_layer(wl["name"]):
             v = bench.metric_reader(m["name"])(ctx)

@@ -1,8 +1,9 @@
 """paged_prefill's share of its roofline: the FLOPs and bytes the
-window's prefill chunks need (``work.paged_prefill`` of each chunk's
-``start`` and ``length``: causal queries over the context and the chunk,
-the context's KV read and the chunk's written, every layer) over the
-kernel's summed device time inside the chunk program, in percent.
+window's prefill chunks need (the family's ``prefill_attention_work``
+of each chunk's ``start`` and ``length``: causal queries over the
+context and the chunk, the context's KV read and the chunk's written,
+every layer) over the kernel's summed device time inside the chunk
+program, in percent.
 
 Each chunk's (start, length) is what the engine put on its
 ``engine.prefill_chunk`` span (``runtime/tracing.py``).  The benchmark's
@@ -12,7 +13,7 @@ spans gives no reading.  Beside the value it notes the bound, the
 chunks and kernel calls counted, and the tiles the engine recorded
 (``EngineMetrics.attn_ppcb``, ``prefill_bh``) where it has them.
 """
-from harness import trace as trace_mod, work
+from harness import trace as trace_mod
 from harness.cells import BENCH_DIR
 from harness.roofline import CHUNK, kernel_seconds, program_spans, share
 
@@ -57,10 +58,7 @@ def read(ctx):
     chunks = window_chunks(path, *ctx.trace["window_ns"])
     if not chunks:
         return None
-    flops = nbytes = 0.0
-    for start, length in chunks:
-        f, b = work.paged_prefill(ctx.dims, start, length)
-        flops, nbytes = flops + f, nbytes + b
+    flops, nbytes = ctx.family.prefill_attention_work(ctx, chunks)
     got = share(flops, nbytes, secs, ctx.peaks)
     if got is None:
         return None

@@ -2,10 +2,11 @@
 served with PTQ1.61 weights.
 
 It imports nothing of the program.  It draws the same bf16 weights from
-the seed (``harness.draw``), quantizes them itself by the recipe the
-configuration states — per weight (QKV and gate+up concatenated along
-the outputs): rank input channels by mean |w|, keep the top
-``quant_ratio`` (rounded to ``salient_multiple``) as per-channel
+the seed through its family's draw (``bench/families/qwen_dense.py``,
+which the program's weights are built with too), quantizes them itself
+by the recipe the configuration states — per weight (QKV and gate+up
+concatenated along the outputs): rank input channels by mean |w|, keep
+the top ``quant_ratio`` (rounded to ``salient_multiple``) as per-channel
 asymmetric int4 (min/max, 15 levels), binarize the rest as sign(w)
 times the mean |w| of that output column over the binary rows — and
 runs the whole forward in float32 at ``Precision.HIGHEST``: RMSNorm,
@@ -28,6 +29,7 @@ from __future__ import annotations
 
 import functools
 import math
+from pathlib import Path
 from typing import Dict, List, Sequence, Tuple
 
 import jax
@@ -35,7 +37,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from harness import draw
-from harness.spec import Dims, dims, salient_channels
+from harness.cells import load_module
+from harness.spec import salient_channels
+
+FAMILY = load_module(Path(__file__).resolve().parents[1], "families",
+                     "qwen_dense")
+Dims, dims = FAMILY.Dims, FAMILY.dims
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 256          # query rows per attention block
@@ -124,7 +131,7 @@ def _attention(q, k, v, lowp):
 def _layer(key, dm: Dims, init_items: Tuple, recipe: Tuple, x, lowp):
     init = dict(init_items)
     ratio, multiple = recipe
-    w = draw.draw_layer(key, dm, init, recipe)
+    w = FAMILY.draw_layer(key, dm, init, recipe)
     f32 = lambda a: a.astype(jnp.float32)
     qd, kvd = dm.hq * dm.dh, dm.hkv * dm.dh
     wqkv = dequantize(jnp.concatenate(
@@ -161,7 +168,7 @@ def _layer(key, dm: Dims, init_items: Tuple, recipe: Tuple, x, lowp):
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 4))
 def _embed(seed_k, dm: Dims, init_items: Tuple, tokens, lowp):
-    e = draw.draw_embed(seed_k, dm, dict(init_items))
+    e = FAMILY.draw_embed(seed_k, dm, dict(init_items))
     return _store(jnp.take(e, tokens, axis=0).astype(jnp.float32), lowp)
 
 
@@ -169,8 +176,9 @@ def _embed(seed_k, dm: Dims, init_items: Tuple, tokens, lowp):
 def _head(seed_k, dm: Dims, init_items: Tuple, rows, lowp):
     """rows (R, d) final hidden states -> (R, vocab) f32 logits."""
     init = dict(init_items)
-    e = _store(draw.draw_embed(seed_k, dm, init).astype(jnp.float32), lowp)
-    fn = draw.draw_final_norm(seed_k, dm, init).astype(jnp.float32)
+    e = _store(FAMILY.draw_embed(seed_k, dm, init).astype(jnp.float32),
+               lowp)
+    fn = FAMILY.draw_final_norm(seed_k, dm, init).astype(jnp.float32)
     h = _f8(_rms(rows, fn, dm.eps), lowp)
     return _mm(h, e.T)
 

@@ -21,7 +21,7 @@ DATA = BENCH / "tests" / "data"
 
 
 def _ctx(red):
-    return driver.Context(dims=None, spec=None, window=None,
+    return driver.Context(dims=None, family=None, spec=None, window=None,
                           engine_metrics=None, trace=red, peaks=None)
 
 
@@ -127,8 +127,10 @@ def test_traced_run_reads_host_tick_ms_here(tmp_path, monkeypatch):
     from test_bench_harness import TINY, TINY_CHAT
 
     bench_dir = tmp_path / "bench"
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "families"):
         (bench_dir / sub).mkdir(parents=True)
+    (bench_dir / "families" / "qwen_dense.py").write_text(
+        (BENCH / "families" / "qwen_dense.py").read_text())
     (bench_dir / "configs" / "tiny.json").write_text(json.dumps(TINY))
     (bench_dir / "traffic" / "tiny_chat.json").write_text(
         json.dumps(TINY_CHAT))
@@ -151,7 +153,7 @@ def test_traced_run_reads_host_tick_ms_here(tmp_path, monkeypatch):
         return 0.0, d * 1e-9, {"device_ops": [], "idle_gaps": []}
 
     monkeypatch.setattr(cells, "BENCH_DIR", bench_dir)
-    monkeypatch.setattr(driver, "check_kernels", lambda engine: {})
+    monkeypatch.setattr(driver, "check_kernels", lambda engine, kernels: {})
     monkeypatch.setattr(driver, "_reduce_trace", reduce_host_only)
     monkeypatch.setattr(driver, "_device_summary", window_only)
     monkeypatch.setattr(driver, "peaks", lambda kind: None)

@@ -14,10 +14,11 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-from harness import driver, trace, weights, work  # noqa: E402
+from harness import driver, trace  # noqa: E402
 from harness.cells import Benchmark  # noqa: E402
 from harness.peaks import peaks  # noqa: E402
-from harness.spec import dims  # noqa: E402
+
+FAMILY = Benchmark().family("qwen_dense")
 
 # Qwen3's mechanisms at a CPU size: per-head q/k RMSNorm, no QKV bias,
 # 8 query / 2 KV heads (4 a group) of 64, so q heads x head_dim (512) is
@@ -26,6 +27,7 @@ SMALL = {
     "name": "qwen3-small",
     "source": "a reduced Qwen3-style decoder for CPU tests",
     "reference": "qwen_dense",
+    "family": "qwen_dense",
     "published": {"hidden_size": 256, "intermediate_size": 512,
                   "num_hidden_layers": 2, "num_attention_heads": 8,
                   "num_key_value_heads": 2, "rms_norm_eps": 1e-06,
@@ -67,12 +69,11 @@ def served():
     from repro.runtime.engine import Engine
     from repro.runtime.events import TokenEvent
 
-    cfg, params = weights.build_params(SMALL, SEED)
+    cfg, params = FAMILY.build_params(SMALL, SEED)
     dep = SMALL["deployment"]
     eng = Engine(cfg, Parallel(), params, n_slots=2, max_seq=MAX_SEQ,
                  paged=True, page_size=dep["page_size"], paged_kernel=True,
-                 chunked_prefill=True, prefill_chunk=dep["prefill_chunk"],
-                 time_phases=False)
+                 chunked_prefill=True, prefill_chunk=dep["prefill_chunk"])
     be = eng.backend
     chunk_fn, decode_fn = be.prefill_chunk, be.decode
     chunks, steps = [], []
@@ -129,7 +130,7 @@ def test_qwen3_4b_file_matches_the_program_registry():
     """The configuration file the benchmark serves and the program's own
     ``qwen3-4b`` agree on every shape and on the RoPE base."""
     from repro.configs import registry
-    got = weights.arch_config(Benchmark().config("qwen3-4b"))
+    got = FAMILY.arch_config(Benchmark().config("qwen3-4b"))
     want = registry.get("qwen3-4b")
     for field in ("d_model", "n_heads", "n_kv_heads", "head_dim_", "d_ff",
                   "vocab", "n_layers", "qkv_bias", "qk_norm",
@@ -140,7 +141,7 @@ def test_qwen3_4b_file_matches_the_program_registry():
 
 
 CHAT = BENCH / "tests" / "data" / "trace_v5e_chat.json.gz"
-QWEN25 = dims(Benchmark().config("qwen2.5-3b"))
+QWEN25 = FAMILY.dims(Benchmark().config("qwen2.5-3b"))
 
 
 @pytest.fixture(scope="module")
@@ -155,7 +156,8 @@ def chat():
 
 
 def _ctx(red, em=None):
-    return driver.Context(dims=QWEN25, spec=Benchmark().config("qwen2.5-3b"),
+    return driver.Context(dims=QWEN25, family=FAMILY,
+                          spec=Benchmark().config("qwen2.5-3b"),
                           window=driver.Window(), engine_metrics=em,
                           trace=red, peaks=peaks("TPU v5 lite"))
 
@@ -187,7 +189,7 @@ def test_paged_prefill_roofline_reads_the_chunk_spans(chat, tmp_path):
     read = Benchmark().metric_reader("paged_prefill_roofline")
     read.__globals__["TRACE_DIR"] = tmp_path
     ctx = _ctx(red, types.SimpleNamespace(attn_ppcb=32, prefill_bh=2))
-    f, b = work.paged_prefill(QWEN25, 512, 256)
+    f, b = FAMILY.paged_prefill(QWEN25, 512, 256)
     want = 100 * max(f / 197e12, b / 819e9) / 5028554e-9
     assert read(ctx) == pytest.approx(want)
     note = ctx.derived["paged_prefill_roofline"]

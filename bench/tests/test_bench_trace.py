@@ -3,6 +3,7 @@ from a trace to metrics on a small trace recorded on a TPU v5e."""
 import gzip
 import json
 import sys
+import types
 from pathlib import Path
 
 import pytest
@@ -10,11 +11,12 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-from harness import roofline, spec, trace, work  # noqa: E402
+from harness import roofline, trace, work  # noqa: E402
 from harness.cells import Benchmark  # noqa: E402
 from harness.peaks import peaks  # noqa: E402
 
-QWEN25 = spec.dims(Benchmark().config("qwen2.5-3b"))
+FAMILY = Benchmark().family("qwen_dense")
+QWEN25 = FAMILY.dims(Benchmark().config("qwen2.5-3b"))
 
 
 def test_mixed_matmul_hand_count():
@@ -27,14 +29,14 @@ def test_mixed_matmul_hand_count():
 
 
 def test_paged_attention_hand_count():
-    flops, nbytes = work.paged_attention(QWEN25, [100, 200])
+    flops, nbytes = FAMILY.paged_attention(QWEN25, [100, 200])
     assert flops == 4 * 16 * 128 * 300 * 36
     # 1 KiB of K+V per token per layer (2 heads of 128, bf16), q and out
     assert nbytes == (300 * 1024 + 2 * 2 * 2 * 16 * 128) * 36
 
 
 def test_paged_prefill_hand_count():
-    flops, nbytes = work.paged_prefill(QWEN25, 256, 100)
+    flops, nbytes = FAMILY.paged_prefill(QWEN25, 256, 100)
     keys = 100 * 256 + 100 * 101 / 2
     assert flops == 4 * 16 * 128 * keys * 36
     assert nbytes == ((256 + 200) * 1024 + 2 * 2 * 100 * 16 * 128) * 36
@@ -42,7 +44,7 @@ def test_paged_prefill_hand_count():
 
 def test_decode_token_flops_counts_head_and_context():
     lin = (2048 * 2560 + 2048 * 2048 + 2048 * 22016 + 11008 * 2048) * 36
-    assert work.decode_token_flops(QWEN25, 10) == \
+    assert FAMILY.decode_token_flops(QWEN25, 10) == \
         2 * (lin + 2048 * 151936) + 4 * 16 * 128 * 10 * 36
 
 
@@ -84,13 +86,14 @@ def recorded():
     return plain, red, breakdown
 
 
-def _ctx(red):
+def _ctx(red, stamps=(-0.5, 0.3, 0.6), family=FAMILY):
     from harness import driver, e2e
     w = driver.Window(t0=0.0, t1=1.0)
     for i in range(64):            # two decoded tokens per slot, ctx 301/302
         w.records[i] = e2e.Record(due=-1.0, prompt_len=300,
-                                  stamps=[-0.5, 0.3, 0.6])
-    return driver.Context(dims=QWEN25, spec=Benchmark().config("qwen2.5-3b"),
+                                  stamps=list(stamps))
+    return driver.Context(dims=QWEN25, family=family,
+                          spec=Benchmark().config("qwen2.5-3b"),
                           window=w, engine_metrics=None, trace=red,
                           peaks=peaks("TPU v5 lite"))
 
@@ -142,21 +145,62 @@ def test_metric_readers_on_the_recorded_trace(recorded):
     assert read("device_idle_share") == pytest.approx(
         100 * (1 - 0.27542861 / 0.287260271))
     # mixed_matmul: 128 decoded rows over 2 steps, weights read per step
-    f1, b1 = work.decode_matmuls(QWEN25, 1, 0.2, 128)
-    f0, b0 = work.decode_matmuls(QWEN25, 0, 0.2, 128)
+    f1, b1 = FAMILY.decode_matmuls(QWEN25, 1, 0.2, 128)
+    f0, b0 = FAMILY.decode_matmuls(QWEN25, 0, 0.2, 128)
     t = 18204912e-9
     want = 100 * max(128 * f1 / 197e12, (2 * b0 + 128 * (b1 - b0)) / 819e9) / t
     assert read("mixed_matmul_roofline") == pytest.approx(want)
-    f, b = work.paged_attention(QWEN25, [301] * 64 + [302] * 64)
+    f, b = FAMILY.paged_attention(QWEN25, [301] * 64 + [302] * 64)
     want = 100 * max(f / 197e12, b / 819e9) / 147668815e-9
     assert read("paged_attention_roofline") == pytest.approx(want)
-    flops = sum(work.decode_token_flops(QWEN25, c)
+    flops = sum(FAMILY.decode_token_flops(QWEN25, c)
                 for c in [301] * 64 + [302] * 64)
     wall = (281260271.0 - 2000000.0) * 1e-9
     assert read("step_mfu") == pytest.approx(100 * flops / wall / 197e12)
     for name in ("mixed_matmul_roofline", "paged_attention_roofline",
                  "step_mfu"):
         assert 0 < read(name) < 100
+
+
+DECODE_READERS = ("mixed_matmul_roofline", "paged_attention_roofline",
+                  "step_mfu")
+
+
+def test_readers_take_the_work_from_the_context_family(recorded):
+    """The decode path's rooflines and ``step_mfu`` count the work through
+    ``ctx.family``: a family that counts three times the dense work reads
+    three times as high on the same trace."""
+    _, red, _ = recorded
+    times3 = lambda got: tuple(3 * x for x in got)  # noqa: E731
+    family = types.SimpleNamespace(
+        decode_matmul_work=lambda ctx, steps, rows: times3(
+            FAMILY.decode_matmul_work(ctx, steps, rows)),
+        decode_attention_work=lambda ctx, rows: times3(
+            FAMILY.decode_attention_work(ctx, rows)),
+        decode_step_flops=lambda ctx, rows: 3 * FAMILY.decode_step_flops(
+            ctx, rows))
+    bench = Benchmark()
+    for name in DECODE_READERS:
+        read = bench.metric_reader(name)
+        assert read(_ctx(red, family=family)) == \
+            pytest.approx(3 * read(_ctx(red))), name
+
+
+def test_readers_count_only_the_traced_decode_steps(recorded):
+    """Where the trace stops before the window's end (the profiler's cap),
+    the decode readers count the rows of the traced steps only: a third
+    decode tick that the trace does not hold leaves each reading as it
+    is."""
+    _, red, _ = recorded
+    cut = _ctx(red, stamps=(-0.5, 0.3, 0.6, 0.9))
+    full = _ctx(red)
+    assert len(cut.decode_rows()) == 3 * 64
+    assert cut.decode_rows(2) == full.decode_rows() == full.decode_rows(2)
+    assert cut.decode_rows(3) == cut.decode_rows(9) == cut.decode_rows()
+    bench = Benchmark()
+    for name in DECODE_READERS:
+        read = bench.metric_reader(name)
+        assert read(cut) == read(full), name
 
 
 def test_metric_modules_agree_with_benchmark_json():
@@ -202,6 +246,6 @@ def test_chunk_and_decode_steps_of_a_chat_tick():
         pytest.approx(40.756949)
     secs, calls = kernel_seconds(ctx, "paged_prefill", CHUNK)
     assert (secs, calls) == (pytest.approx(5028554e-9), 36)
-    f, b = work.paged_prefill(QWEN25, 512, 256)
+    f, b = FAMILY.paged_prefill(QWEN25, 512, 256)
     want = 100 * max(f / 197e12, b / 819e9) / 5028554e-9
     assert share(f, b, secs, ctx.peaks)[0] == pytest.approx(want)
